@@ -28,10 +28,8 @@ import numpy as np
 
 from benchmarks.common import csv
 from repro.kernels import autotune, ref
-from repro.kernels.decode_attn import (paged_decode_attention,
-                                       paged_verify_attention)
-from repro.kernels.splitk import (paged_decode_attention_splitk,
-                                  paged_verify_attention_splitk)
+from repro.kernels.decode_attn import paged_attention
+from repro.kernels.splitk import paged_verify_attention_splitk
 
 
 def _serial_loop(x, a, b, ids, n):
@@ -73,8 +71,9 @@ def smlm_micro(T: int = 4096, d: int = 512, r: int = 8, o: int = 512):
 # --------------------------------------------------- split-K attention sweep
 
 # decode arms: (B, h, g, hd, bs, nbt).  The long-context/small-batch arm is
-# the one flash decoding exists for (B*h alone cannot fill the lanes); the
-# batched arm shows the heuristic correctly declining to split.
+# the one flash decoding exists for (the B*g grid cells alone cannot fill
+# the lanes); the batched arm shows the heuristic correctly declining to
+# split.
 ARMS = {
     "long_ctx_small_batch": dict(B=1, h=4, g=2, hd=64, bs=16, nbt=32),
     "long_ctx_batched": dict(B=8, h=4, g=2, hd=64, bs=16, nbt=32),
@@ -110,7 +109,7 @@ def _allclose(a, b, tol=2e-5):
 def _arm_result(name, spec):
     B, h, g, hd, bs, nbt = (spec[k] for k in ("B", "h", "g", "hd", "bs",
                                               "nbt"))
-    bh = B * h
+    bh = B * g                   # grid cells per split: (request, KV head)
     cfg = autotune.choose(hd, bs, nbt, bh)
     ns = cfg.num_splits
 
@@ -118,10 +117,12 @@ def _arm_result(name, spec):
     # over the SAME pool (equal HBM — split-K adds only O(ns*B*h*hd) fp32
     # partials, transient epilogue traffic, not pool residency)
     q, kp, vp, tbl, pos = _paged_problem(B, h, g, hd, bs, nbt, seed=hash(name) % 1000)
-    y_seq = paged_decode_attention(q, kp, vp, tbl, pos, interpret=True)
-    y_spl = paged_decode_attention_splitk(q, kp, vp, tbl, pos,
+    ones = jnp.ones((B,), jnp.int32)
+    y_seq = paged_attention(q[:, None], kp, vp, tbl, pos, ones,
+                            interpret=True)[:, 0]
+    y_spl = paged_verify_attention_splitk(q[:, None], kp, vp, tbl, pos, ones,
                                           num_splits=max(ns, 2),
-                                          interpret=True)
+                                          interpret=True)[:, 0]
     y_ref = ref.paged_decode_ref(q, kp, vp, tbl, pos)
     exact = (_allclose(y_spl, y_seq) and _allclose(y_spl, y_ref))
 
@@ -131,8 +132,7 @@ def _arm_result(name, spec):
                                               Sq=Sq)
     lens = jnp.asarray(np.random.default_rng(7).integers(1, Sq + 1, B),
                        jnp.int32)
-    yv_seq = paged_verify_attention(qv, kpv, vpv, tblv, posv, lens,
-                                    interpret=True)
+    yv_seq = paged_attention(qv, kpv, vpv, tblv, posv, lens, interpret=True)
     yv_spl = paged_verify_attention_splitk(qv, kpv, vpv, tblv, posv, lens,
                                            num_splits=max(ns, 2),
                                            interpret=True)

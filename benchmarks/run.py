@@ -123,6 +123,8 @@ def main(argv=None) -> None:
     if args.summarize_only:
         sys.exit(summarize_only(args.dir))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     rows = {}

@@ -58,10 +58,12 @@ def lora_apply_ref(x: jax.Array, a: jax.Array, b: jax.Array,
 
 def lora_apply(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
                scale_t: Optional[jax.Array] = None,
-               impl: str = "auto") -> jax.Array:
-    """Dispatch between the Pallas SMLM kernel (TPU) and the jnp oracle."""
-    if impl == "auto":
-        impl = "smlm" if jax.default_backend() == "tpu" else "ref"
+               impl: str = "ref") -> jax.Array:
+    """Multi-LoRA matmul.  ``ref`` (every served and trained path, on every
+    backend) is the differentiable jnp one-hot form.  ``smlm`` runs the
+    compiled Pallas SMLM kernel and ``interpret`` the same kernel in
+    interpret mode; both need adapter-uniform ``block_t`` tiles and have no
+    VJP, so only kernel tests call them."""
     if impl == "ref":
         return lora_apply_ref(x, a, b, ids, scale_t)
     from repro.kernels import ops as kops
@@ -70,7 +72,7 @@ def lora_apply(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
 
 def dense(x: jax.Array, w: jax.Array, bias: Optional[jax.Array],
           lora: Optional[dict], ids: Optional[jax.Array],
-          scale_t: Optional[jax.Array] = None, impl: str = "auto"
+          scale_t: Optional[jax.Array] = None, impl: str = "ref"
           ) -> jax.Array:
     """Joint base + multi-LoRA linear over a flattened token stream [T, d].
 

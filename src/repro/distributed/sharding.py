@@ -201,25 +201,13 @@ def act_constraint_fn(mesh):
 
 
 def mesh_context(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh —
-    ``jax.set_mesh`` on new jax, the Mesh object itself (a context manager)
-    on older releases."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def ambient_mesh():
-    """The mesh currently in scope, or None.  Compat wrapper:
-    ``jax.sharding.get_abstract_mesh`` only exists on newer jax; older
-    releases expose the ambient mesh via the thread-local resource env."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-        return None if pm.empty else pm
-    except Exception:
-        return None
+    """The mesh currently in scope (empty when none is)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def maybe_constrain(x, *spec):

@@ -4,7 +4,9 @@ The sequential kernels hard-coded their tiles (``block_k = 512`` for the
 linear-cache decode kernel, one pool block per grid step for the paged
 family) and the split-K kernels need a ``num_splits``.  This module owns
 that choice, per shape key ``(head_dim, block_size, nbt, bh)`` where ``bh =
-B * n_heads`` is the batch-parallelism the grid already has:
+B * n_kv_heads`` is the batch-parallelism the grid already has (the paged
+kernels run one grid cell per request and KV head, each scoring its whole
+GQA query group):
 
 * a tuning TABLE — in-memory dict, loadable from / savable to a small JSON
   file — populated by a ``benchmarks/bench_kernels.py``-driven sweep

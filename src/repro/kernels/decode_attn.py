@@ -109,199 +109,170 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# paged variant: the KV cache is a flat block pool, each request's blocks are
-# gathered through a scalar-prefetched block table in the BlockSpec index map
-# — the DMA engine walks the table, the kernel never sees the indirection.
+# paged attention: the KV cache is a flat block pool, each request's blocks
+# are gathered through a scalar-prefetched block table in the BlockSpec
+# index map — the DMA engine walks the table, the kernel never sees the
+# indirection.  ONE kernel serves every paged query shape: batch decode is a
+# one-token chunk, speculative verify a (1 + k)-token chunk (k drafts
+# checked in one cache pass instead of k decodes), and suffix-only prefill
+# a chunk tiled along the query axis — all attend to keys
+# ``0 .. start + len - 1`` with positional causality.  The chunk's own K/V
+# are written to the pool before the call, so the kernel never tells
+# cached prefix keys from fresh ones: a shared prefix is read, not
+# recomputed.
+#
+# TPU layout (the Mosaic compiler wants the last two block dims divisible by
+# (8, 128) or equal to the array's): the pool [nb, bs, g, hd] is viewed as
+# [nb, bs, g*hd] (a free reshape) so one KV head of one block is a (bs, hd)
+# tile, and the queries are regrouped per KV head as [B, g, Sq*m, hd] (row
+# ``t*m + i`` is token t, query head i of the group) so each grid step
+# scores the whole GQA group against the block it streams: the block is
+# DMA'd once per KV head, not once per query head.
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, bs: int, nbt: int,
-                         scale: float):
-    b = pl.program_id(0)
-    ib = pl.program_id(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0, :]                                         # [hd]
-    k = k_ref[0, :, 0, :]                                      # [bs, hd]
-    v = v_ref[0, :, 0, :]
-    pos = pos_ref[b]
-    # absolute position of slot j within block ib of this request's table
-    j = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    mask = j <= pos                    # null-padded table rows fail this too
-    s = jnp.dot(k, q, preferred_element_type=jnp.float32) * scale  # [bs]
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev, l_prev = m_ref[0], l_ref[0]
-    m_new = jnp.maximum(m_prev, s.max())
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-    l_ref[0] = l_prev * corr + p.sum()
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)[None]
-    m_ref[0] = m_new
-
-    @pl.when(ib == nbt - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[0] / l).astype(o_ref.dtype)
+# lane width of the per-row softmax statistics: m and l live lane-replicated
+# in [rows, LANES] f32 scratch (a (rows, 1) scratch would not tile)
+LANES = 128
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_tables: jax.Array,
-                           pos: jax.Array, *,
-                           interpret: bool = False) -> jax.Array:
-    """Block-table batch-decode attention over a paged KV pool.
-
-    q: [B, h, hd] current-token queries;
-    k_pool/v_pool: [n_blocks, bs, g, hd] flat block pool (the persistent
-        cache — only the blocks a request's table names are streamed in);
-    block_tables: [B, nbt] int32 per-request block ids, null-padded (padding
-        entries are clamped to block 0 and masked out via ``pos``);
-    pos: [B] int32 current positions (block ``pos // bs`` holds the newest
-        token).  Returns [B, h, hd].
-
-    Grid (B, h, nbt): one grid step per table entry; the BlockSpec index map
-    reads the scalar-prefetched table so each step DMAs exactly one block of
-    the pool — the gather lives in the index map, not in HBM.
-    """
-    B, h, hd = q.shape
-    bs, g = k_pool.shape[1], k_pool.shape[2]
+def group_queries(q: jax.Array, g: int, sq_pad: int) -> jax.Array:
+    """[B, Sq, h, hd] -> [B, g, sq_pad*m, hd]: GQA group-major query rows
+    (token-major within a group), zero-padded along the chunk."""
+    B, Sq, h, hd = q.shape
     m = h // g
-    nbt = block_tables.shape[1]
-    tbl = jnp.maximum(block_tables.astype(jnp.int32), 0)
-    scale = hd ** -0.5
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, h, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, hq, ib, T_, P_: (b, hq, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, hq, ib, T_, P_: (T_[b, ib], 0, hq // m, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, hq, ib, T_, P_: (T_[b, ib], 0, hq // m, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, hq, ib, T_, P_: (b, hq, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-        ],
-    )
-    kern = functools.partial(_paged_decode_kernel, bs=bs, nbt=nbt,
-                             scale=scale)
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, hd), q.dtype),
-        interpret=interpret,
-    )(tbl, pos.astype(jnp.int32), q, k_pool, v_pool)
+    q = q.reshape(B, Sq, g, m, hd).transpose(0, 2, 1, 3, 4)
+    if sq_pad > Sq:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_pad - Sq), (0, 0), (0, 0)))
+    return q.reshape(B, g, sq_pad * m, hd)
 
 
-# ---------------------------------------------------------------------------
-# verify variant: chunked query over block tables — the speculative-decoding
-# attention.  Each request contributes Sq = 1 + k query tokens (current token
-# + drafts) at positions pos .. pos + Sq - 1; the online softmax streams the
-# same block walk as batch decode but scores an [Sq, bs] tile per block, so
-# verifying k drafts costs one cache pass instead of k sequential decodes.
-# ---------------------------------------------------------------------------
+def ungroup_queries(o: jax.Array, h: int, sq: int) -> jax.Array:
+    """Inverse of ``group_queries``: [..., g, sq_pad*m, hd] ->
+    [..., sq, h, hd] over any leading axes."""
+    *lead, g, rows, hd = o.shape
+    m = h // g
+    o = o.reshape(*lead, g, rows // m, m, hd)
+    o = jnp.moveaxis(o, -4, -3)                  # [..., sq_pad, g, m, hd]
+    return o.reshape(*lead, rows // m, h, hd)[..., :sq, :, :]
 
-def _paged_verify_kernel(tbl_ref, pos_ref, len_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_ref, m_ref, l_ref, *, bs: int, nbt: int,
-                         sq: int, scale: float):
-    b = pl.program_id(0)
-    ib = pl.program_id(2)
 
-    @pl.when(ib == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, :, 0, :]                                      # [sq, hd]
-    k = k_ref[0, :, 0, :]                                      # [bs, hd]
-    v = v_ref[0, :, 0, :]
-    pos, ln = pos_ref[b], len_ref[b]
-    j = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (sq, bs), 1)
-    qi = pos + jax.lax.broadcasted_iota(jnp.int32, (sq, bs), 0)
-    # causal within the chunk, valid through the chunk's written length
-    # (null-padded table rows exceed pos + ln and fail this too)
-    mask = (j <= qi) & (j < pos + ln)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [sq,bs]
+def online_softmax_step(q, k, v, mask, scale, acc_ref, m_ref, l_ref):
+    """One block of the streaming softmax: score ``q`` [rows, hd] against
+    ``k``/``v`` [bs, hd] under ``mask`` [rows, bs] and fold the block into
+    the running (acc [rows, hd], m/l [rows, LANES]) scratch."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask, s, NEG_INF)
     m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
     corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-    l_ref[...] = l_prev * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+    l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr[:, :1] + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
+
+def chunk_mask(rows: int, bs: int, m: int, ib, q0, start, ln):
+    """[rows, bs] mask of key slot ``ib*bs + j`` for query row ``r`` (token
+    ``q0 + r // m`` of the chunk): causal over absolute positions, and
+    valid through the chunk's written length ``start + ln``.  Null-padded
+    table entries lie past ``start + ln`` and fail it too."""
+    j = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+    t = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0),
+                    jnp.int32(m))
+    qi = start + q0 + t
+    return (j <= qi) & (j < start + ln)
+
+
+def _paged_attn_kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref, v_ref,
+                       o_ref, acc_ref, m_ref, l_ref, *, bs: int, nbt: int,
+                       m: int, block_q: int, scale: float):
+    b = pl.program_id(0)
+    iq = pl.program_id(2)
+    ib = pl.program_id(3)
+
+    @pl.when(ib == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    rows = block_q * m
+    mask = chunk_mask(rows, bs, m, ib, iq * block_q, start_ref[b],
+                      len_ref[b])
+    online_softmax_step(q_ref[0, 0], k_ref[0], v_ref[0], mask, scale,
+                        acc_ref, m_ref, l_ref)
+
     @pl.when(ib == nbt - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_tables: jax.Array,
-                           pos: jax.Array, lens: jax.Array, *,
-                           interpret: bool = False) -> jax.Array:
-    """Speculative verify attention over a paged KV pool.
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,  # reprolint: oracle=paged_prefill_ref
+                    block_tables: jax.Array, start: jax.Array,
+                    lens: jax.Array, *, block_q: int = 128,
+                    interpret: bool = False) -> jax.Array:
+    """Chunked-query attention over a paged KV pool.
 
-    q: [B, Sq, h, hd] chunk queries (current token + drafts, already roped);
+    q: [B, Sq, h, hd] chunk queries at absolute positions
+        ``start .. start + Sq - 1`` (already roped);
     k_pool/v_pool: [n_blocks, bs, g, hd] flat block pool — the chunk's own
-        K/V must already be written at positions ``pos .. pos + lens - 1``;
-    block_tables: [B, nbt] int32 per-request block ids, null-padded;
-    pos: [B] int32 chunk start positions (= cache length before the chunk);
-    lens: [B] int32 valid chunk lengths (1 = plain decode row, 0 = padding —
-        such rows produce zeros).  Returns [B, Sq, h, hd].
+        K/V must already be written at ``start .. start + lens - 1``;
+    block_tables: [B, nbt] int32 per-request block ids, null-padded
+        (padding entries are clamped to block 0 and masked out);
+    start: [B] int32 tokens already in the cache before the chunk;
+    lens: [B] int32 valid chunk lengths (0 = padding row -> zeros; query
+        rows past ``lens`` produce values the caller never reads).
+    Returns [B, Sq, h, hd].
 
-    Grid (B, h, nbt): identical block walk to ``paged_decode_attention``,
-    but each step scores all Sq chunk queries against the streamed block —
-    the time-axis analogue of batching more requests per launch.
+    Grid (B, g, nq, nbt): for a fixed (request, KV head, query tile) the
+    block walk is innermost, so the VMEM accumulator carries the online
+    softmax across the table; each step DMAs exactly one (bs, hd) tile of
+    one KV head and scores the tile's whole query group against it.
     """
     B, Sq, h, hd = q.shape
-    bs, g = k_pool.shape[1], k_pool.shape[2]
+    nb, bs, g = k_pool.shape[:3]
     m = h // g
     nbt = block_tables.shape[1]
     tbl = jnp.maximum(block_tables.astype(jnp.int32), 0)
-    scale = hd ** -0.5
+    bq = min(block_q, max(Sq, 1))
+    nq = -(-Sq // bq)
+    qg = group_queries(q, g, nq * bq)
+    kp = k_pool.reshape(nb, bs, g * hd)
+    vp = v_pool.reshape(nb, bs, g * hd)
+    rows = bq * m
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, h, nbt),
+        grid=(B, g, nq, nbt),
         in_specs=[
-            pl.BlockSpec((1, Sq, 1, hd),
-                         lambda b, hq, ib, T_, P_, L_: (b, 0, hq, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, hq, ib, T_, P_, L_:
-                         (T_[b, ib], 0, hq // m, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, hq, ib, T_, P_, L_:
-                         (T_[b, ib], 0, hq // m, 0)),
+            pl.BlockSpec((1, 1, rows, hd),
+                         lambda b, kh, iq, ib, T_, S_, L_: (b, kh, iq, 0)),
+            pl.BlockSpec((1, bs, hd),
+                         lambda b, kh, iq, ib, T_, S_, L_:
+                         (T_[b, ib], 0, kh)),
+            pl.BlockSpec((1, bs, hd),
+                         lambda b, kh, iq, ib, T_, S_, L_:
+                         (T_[b, ib], 0, kh)),
         ],
-        out_specs=pl.BlockSpec((1, Sq, 1, hd),
-                               lambda b, hq, ib, T_, P_, L_: (b, 0, hq, 0)),
+        out_specs=pl.BlockSpec((1, 1, rows, hd),
+                               lambda b, kh, iq, ib, T_, S_, L_:
+                               (b, kh, iq, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Sq, hd), jnp.float32),
-            pltpu.VMEM((Sq,), jnp.float32),
-            pltpu.VMEM((Sq,), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((rows, LANES), jnp.float32),
+            pltpu.VMEM((rows, LANES), jnp.float32),
         ],
     )
-    kern = functools.partial(_paged_verify_kernel, bs=bs, nbt=nbt, sq=Sq,
-                             scale=scale)
-    return pl.pallas_call(
+    kern = functools.partial(_paged_attn_kernel, bs=bs, nbt=nbt, m=m,
+                             block_q=bq, scale=hd ** -0.5)
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
-    )(tbl, pos.astype(jnp.int32), lens.astype(jnp.int32), q, k_pool, v_pool)
+    )(tbl, start.astype(jnp.int32), lens.astype(jnp.int32), qg, kp, vp)
+    return ungroup_queries(out, h, Sq)

@@ -1,8 +1,9 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On TPU the real kernels run compiled; on CPU (this container) they run in
-``interpret=True`` mode, and callers that only need numerics (the model's
-default path) use the jnp oracles in ``ref.py`` directly.
+Kernels run compiled unless the caller passes ``interpret=True`` (the CPU
+tests do); nothing here picks a mode or an implementation from the
+platform, and a shape a kernel cannot take raises instead of quietly
+running the ``ref.py`` oracle.
 """
 from __future__ import annotations
 
@@ -11,20 +12,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref as _ref
 from repro.kernels.bgmv import bgmv as _bgmv_kernel
 from repro.kernels.decode_attn import decode_attention as _decode_kernel
 from repro.kernels.flash_attn import flash_attention as _flash_kernel
 from repro.kernels.smlm import smlm as _smlm_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def smlm(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
          scale_t: Optional[jax.Array] = None, *, block_t: int = 128,
-         block_o: int = 128, interpret: Optional[bool] = None) -> jax.Array:
+         block_o: int = 128, interpret: bool = False) -> jax.Array:
     """Segmented multi-LoRA matmul over a tile-aligned token stream.
 
     ``ids``/``scale_t`` are PER-TOKEN; the flow planner guarantees each
@@ -41,9 +37,9 @@ def smlm(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
     """
     T = x.shape[0]
     n = a.shape[0]
-    if b.shape[-1] % block_o != 0:
-        sc = scale_t if scale_t is not None else jnp.ones((T,), jnp.float32)
-        return _ref.bgmv_ref(x, a, b, ids, sc)
+    if b.shape[-1] % block_o:
+        raise ValueError(f"smlm: d_out={b.shape[-1]} is not a multiple of "
+                         f"block_o={block_o}")
     rem = T % block_t
     if rem:
         t0 = T - rem
@@ -63,14 +59,13 @@ def smlm(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
     else:
         tile_scale = jnp.where(valid, scale_t[::block_t], 0.0)
     tile_ids = jnp.clip(tile_ids, 0, n - 1)
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return _smlm_kernel(x, a, b, tile_ids, tile_scale,
                         block_t=block_t, block_o=block_o, interpret=interpret)
 
 
 def bgmv(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
          scale_t: Optional[jax.Array] = None, *, block_o: int = 128,
-         interpret: Optional[bool] = None) -> jax.Array:
+         interpret: bool = False) -> jax.Array:
     """Per-token multi-LoRA matmul (decode bucket)."""
     T = x.shape[0]
     n = a.shape[0]
@@ -80,9 +75,6 @@ def bgmv(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
     else:
         scale = jnp.where(valid, scale_t, 0.0)
     ids = jnp.clip(ids, 0, n - 1)
-    if b.shape[-1] % block_o != 0:
-        return _ref.bgmv_ref(x, a, b, ids, scale)
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return _bgmv_kernel(x, a, b, ids, scale, block_o=block_o,
                         interpret=interpret)
 
@@ -90,10 +82,8 @@ def bgmv(x: jax.Array, a: jax.Array, b: jax.Array, ids: jax.Array,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     lengths: jax.Array, *, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Flash attention (prefill).  Falls back to the oracle off-TPU unless
-    ``interpret`` is forced (tests)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+                    interpret: bool = False) -> jax.Array:
+    """Flash attention (prefill)."""
     return _flash_kernel(q, k, v, lengths, causal=causal, block_q=block_q,
                          block_k=block_k, interpret=interpret)
 
@@ -101,8 +91,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pos: jax.Array, *, window: int = 0,
                      block_k: int = 512,
-                     interpret: Optional[bool] = None) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """Batch-decode attention (one token per request over a long cache)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return _decode_kernel(q, k, v, pos, block_k=block_k, window=window,
                           interpret=interpret)

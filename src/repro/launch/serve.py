@@ -15,6 +15,7 @@ from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.core.lora import LoRAConfig
 from repro.core.virtualization import AdapterStore, MixedLoraModel
 from repro.data import datasets, workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineConfig, UnifiedEngine
 from repro.serving.request import PRIORITY_CLASSES, Request
 from repro.serving.slo import SLOConfig, slo_attainment
@@ -106,6 +107,7 @@ def main():
                          "(independent replicas with local dedup only)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.paged_attn_kernel is not None:
         import os

@@ -19,6 +19,7 @@ from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.core.lora import LoRAConfig
 from repro.core.virtualization import AdapterStore, MixedLoraModel
 from repro.data import datasets
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineConfig, UnifiedEngine
 from repro.training.trainer import MixedLoraTrainer, TrainerConfig
 
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--accum", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     from repro.models.schema import init_params

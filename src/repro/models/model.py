@@ -264,7 +264,7 @@ def _paged_write_chunk(pool: jax.Array, xh: jax.Array, tables: jax.Array,
 
 def _paged_view(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """Gather per-request contiguous K/V views ``[Bd, nbt*bs, ...]`` — the
-    jnp reference of what kernels.decode_attn.paged_decode_attention streams
+    jnp reference of what kernels.decode_attn.paged_attention streams
     block-by-block without materializing."""
     tbl = jnp.maximum(tables, 0)
     Bd, nbt = tbl.shape
@@ -387,9 +387,8 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
                 new_cache["k"], new_cache["v"] = ck, cv
                 mode = _paged_kernel_mode()
                 if mode:
-                    from repro.kernels.prefill_attn import \
-                        paged_prefill_attention
-                    outs[1] = paged_prefill_attention(
+                    from repro.kernels.decode_attn import paged_attention
+                    outs[1] = paged_attention(
                         qh, ck, cv, plan.pf_tables, plan.pf_cached,
                         plan.pf.length,
                         interpret=mode.endswith("interpret"))
@@ -445,33 +444,17 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
                     interp = mode.endswith("interpret")
                     if mode.startswith("splitk"):
                         from repro.kernels.autotune import choose
-                        from repro.kernels.splitk import (
-                            paged_decode_attention_splitk,
-                            paged_verify_attention_splitk)
+                        from repro.kernels.splitk import \
+                            paged_verify_attention_splitk
                         kc = choose(hd, ck.shape[1],
-                                    plan.dec_tables.shape[1], plan.Bd * h)
-                        if Sd == 1:
-                            o = paged_decode_attention_splitk(
-                                qh[:, 0], ck, cv, plan.dec_tables,
-                                plan.dec_pos, num_splits=kc.num_splits,
-                                interpret=interp)
-                            outs[2] = o[:, None]
-                        else:
-                            outs[2] = paged_verify_attention_splitk(
-                                qh, ck, cv, plan.dec_tables, plan.dec_pos,
-                                plan.dec_len, num_splits=kc.num_splits,
-                                interpret=interp)
-                    elif Sd == 1:
-                        from repro.kernels.decode_attn import \
-                            paged_decode_attention
-                        o = paged_decode_attention(
-                            qh[:, 0], ck, cv, plan.dec_tables, plan.dec_pos,
+                                    plan.dec_tables.shape[1], plan.Bd * kv)
+                        outs[2] = paged_verify_attention_splitk(
+                            qh, ck, cv, plan.dec_tables, plan.dec_pos,
+                            plan.dec_len, num_splits=kc.num_splits,
                             interpret=interp)
-                        outs[2] = o[:, None]
                     else:
-                        from repro.kernels.decode_attn import \
-                            paged_verify_attention
-                        outs[2] = paged_verify_attention(
+                        from repro.kernels.decode_attn import paged_attention
+                        outs[2] = paged_attention(
                             qh, ck, cv, plan.dec_tables, plan.dec_pos,
                             plan.dec_len, interpret=interp)
                 else:

@@ -71,16 +71,10 @@ def moe_apply_auto(x: jax.Array, params: dict, mcfg: MoEConfig,
     cap = max(int(math.ceil((T // chips) * mcfg.top_k / E
                             * mcfg.capacity_factor)), 1)
 
-    if hasattr(jax, "shard_map"):
-        smap, sm_kw = jax.shard_map, {"check_vma": False}
-    else:                              # jax < 0.5: experimental namespace
-        from jax.experimental.shard_map import shard_map as smap
-        sm_kw = {"check_rep": False}
-
     @functools.partial(
-        smap, mesh=am,
+        jax.shard_map, mesh=am,
         in_specs=(tok_spec, r_spec, w_in_spec, w_in_spec, w_out_spec),
-        out_specs=tok_spec, **sm_kw)
+        out_specs=tok_spec, check_vma=False)
     def inner(xb, rb, wgb, wub, wdb):
         # un-FSDP the weight blocks (the manual analogue of GSPMD's
         # per-layer FSDP all-gather)

@@ -180,7 +180,10 @@ def _init_leaf(key: jax.Array, p: P, dtype) -> jax.Array:
         return jnp.broadcast_to(base, p.shape).astype(dtype)
     fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
     std = min(p.scale, fan_in ** -0.5) if p.init == "normal" else p.scale
-    return (jax.random.normal(key, p.shape) * std).astype(dtype)
+    # drawn in the target dtype: an fp32 draw of a stacked bf16 leaf is a
+    # transient twice the leaf's size (3.8 GB for Llama-3-8B's [16, 4096,
+    # 14336] MLP stack), which a 16 GB chip may not have beside the weights
+    return (jax.random.normal(key, p.shape, dtype) * std).astype(dtype)
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,

@@ -4,9 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.decode_attn import (decode_attention,
-                                       paged_decode_attention,
-                                       paged_verify_attention)
+from repro.kernels.decode_attn import decode_attention, paged_attention
 from repro.kernels.ref import (decode_attention_ref, paged_decode_ref,
                                paged_verify_ref)
 
@@ -74,8 +72,8 @@ def test_paged_decode_kernel_matches_ref(dtype, B, h, g, hd, bs, nbt):
     q = jax.random.normal(kq, (B, h, hd)).astype(dtype)
     k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
     posj = jnp.asarray(pos, jnp.int32)
-    y = paged_decode_attention(q, k_pool, v_pool, tables, posj,
-                               interpret=True)
+    y = paged_attention(q[:, None], k_pool, v_pool, tables, posj,
+                        jnp.ones((B,), jnp.int32), interpret=True)[:, 0]
     yr = paged_decode_ref(q, k_pool, v_pool, tables, posj)
     tol = 2e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -107,26 +105,10 @@ def test_paged_verify_kernel_matches_ref(B, h, g, hd, bs, nbt, Sq):
     posj = jnp.asarray(pos, jnp.int32)
     lensj = jnp.asarray(lens, jnp.int32)
     tj = jnp.asarray(tables)
-    y = paged_verify_attention(q, k_pool, v_pool, tj, posj, lensj,
-                               interpret=True)
+    y = paged_attention(q, k_pool, v_pool, tj, posj, lensj, interpret=True)
     yr = paged_verify_ref(q, k_pool, v_pool, tj, posj, lensj)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=3e-5, atol=3e-5)
-
-
-def test_verify_kernel_sq1_matches_decode_kernel():
-    """A one-token verify chunk IS batch decode: both kernels must agree."""
-    B, h, g, hd, bs, nbt = 2, 4, 2, 16, 8, 4
-    pos = np.array([13, 30])
-    k_pool, v_pool, tables, kq = _paged_setup(B, g, hd, bs, nbt, 16, pos)
-    q = jax.random.normal(kq, (B, h, hd))
-    posj = jnp.asarray(pos, jnp.int32)
-    y1 = paged_verify_attention(q[:, None], k_pool, v_pool, tables, posj,
-                                jnp.ones((B,), jnp.int32), interpret=True)
-    y0 = paged_decode_attention(q, k_pool, v_pool, tables, posj,
-                                interpret=True)
-    np.testing.assert_allclose(np.asarray(y1[:, 0]), np.asarray(y0),
-                               rtol=2e-5, atol=2e-5)
 
 
 def test_model_decode_bucket_kernel_flag(monkeypatch):
@@ -211,8 +193,8 @@ def test_paged_kernel_matches_dense_kernel():
     k_pool, v_pool, tables, kq = _paged_setup(B, g, hd, bs, nbt, 16, pos)
     q = jax.random.normal(kq, (B, h, hd))
     posj = jnp.asarray(pos, jnp.int32)
-    y = paged_decode_attention(q, k_pool, v_pool, tables, posj,
-                               interpret=True)
+    y = paged_attention(q[:, None], k_pool, v_pool, tables, posj,
+                        jnp.ones((B,), jnp.int32), interpret=True)[:, 0]
     tn = np.asarray(tables)
     kd = np.asarray(k_pool)[tn].reshape(B, nbt * bs, g, hd)
     vd = np.asarray(v_pool)[tn].reshape(B, nbt * bs, g, hd)

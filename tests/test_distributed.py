@@ -41,7 +41,8 @@ def test_shardmap_moe_matches_single_device():
                   "w_down": jax.random.normal(ks[3], (E, f, d)) * 0.05}
         x = jax.random.normal(ks[4], (T, d))
         y_ref, aux_ref = moe_apply(x, params, m)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.distributed.sharding import mesh_context
         with mesh_context(mesh):
             y, aux = jax.jit(lambda a, b: moe_apply_auto(a, b, m,
@@ -76,7 +77,8 @@ def test_unified_forward_under_mesh_matches_single_device():
                      adapter=jnp.full((4,), -1))
         ref = unified_forward(cfg, params, UnifiedBatch(pf=pf),
                               cache=init_cache(cfg, 4, 32))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.distributed.sharding import mesh_context
         with mesh_context(mesh):
             got = jax.jit(lambda p, b, c: unified_forward(cfg, p, b, c))(

@@ -12,7 +12,7 @@ from repro.configs import get_reduced
 from repro.core.lora import LoRAConfig
 from repro.core.virtualization import AdapterStore, MixedLoraModel
 from repro.data import datasets
-from repro.kernels.prefill_attn import paged_prefill_attention
+from repro.kernels.decode_attn import paged_attention
 from repro.kernels.ref import paged_prefill_ref
 from repro.models.model import init_paged_cache, unified_forward
 from repro.models.schema import init_params
@@ -54,15 +54,36 @@ def test_paged_prefill_kernel_matches_ref(dtype, B, h, g, hd, bs, nbt, Sq,
     cj = jnp.asarray(cached, jnp.int32)
     sj = jnp.asarray(seg, jnp.int32)
     tj = jnp.asarray(tables)
-    y = np.asarray(paged_prefill_attention(q, k_pool, v_pool, tj, cj, sj,
-                                           block_q=bq, interpret=True),
-                   np.float32)
+    y = np.asarray(paged_attention(q, k_pool, v_pool, tj, cj, sj,
+                                   block_q=bq, interpret=True), np.float32)
     yr = np.asarray(paged_prefill_ref(q, k_pool, v_pool, tj, cj, sj),
                     np.float32)
     tol = 3e-5 if dtype == jnp.float32 else 5e-2
     for b in range(B):  # rows past seg are padding (garbage in both paths)
         np.testing.assert_allclose(y[b, :seg[b]], yr[b, :seg[b]],
                                    rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("Sq,bq", [(1, 128), (4, 128), (20, 8)])
+def test_paged_attention_serves_every_chunk_shape(Sq, bq):
+    """The one paged walk behind decode (Sq 1), verify (Sq 1 + k) and
+    query-tiled prefill == the chunk oracle, at a GQA group of 4."""
+    B, h, g, hd, bs, nbt = 2, 8, 2, 16, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(Sq), 3)
+    n_blocks = nbt * B + 1
+    k_pool = jax.random.normal(ks[0], (n_blocks, bs, g, hd))
+    v_pool = jax.random.normal(ks[1], (n_blocks, bs, g, hd))
+    tables = jnp.arange(1, n_blocks, dtype=jnp.int32)[::-1].reshape(B, nbt)
+    start = jnp.asarray([nbt * bs - Sq - 3, 5], jnp.int32)
+    lens = jnp.asarray([Sq, max(Sq - 1, 1)], jnp.int32)
+    q = jax.random.normal(ks[2], (B, Sq, h, hd))
+    y = np.asarray(paged_attention(q, k_pool, v_pool, tables, start, lens,
+                                   block_q=bq, interpret=True))
+    yr = np.asarray(paged_prefill_ref(q, k_pool, v_pool, tables, start,
+                                      lens))
+    for b in range(B):
+        n = int(lens[b])
+        np.testing.assert_allclose(y[b, :n], yr[b, :n], rtol=3e-5, atol=3e-5)
 
 
 # ----------------------------------------------------- model equivalence
@@ -113,7 +134,7 @@ def test_chunked_suffix_prefill_matches_full(arch, n_chunks):
 
 
 def test_model_prefill_kernel_flag(monkeypatch):
-    """REPRO_PAGED_ATTN_KERNEL wires kernels.prefill_attn into the model's
+    """REPRO_PAGED_ATTN_KERNEL wires the paged attention kernel into the model's
     suffix-prefill bucket: logits must match the jnp gather-view path."""
     cfg = get_reduced("llama3-8b")
     params = init_params(cfg, jax.random.PRNGKey(0))

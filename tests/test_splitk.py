@@ -8,11 +8,9 @@ import pytest
 
 from repro.kernels import autotune
 from repro.kernels.autotune import AttnConfig
-from repro.kernels.decode_attn import (paged_decode_attention,
-                                       paged_verify_attention)
+from repro.kernels.decode_attn import paged_attention
 from repro.kernels.ref import paged_decode_ref, paged_verify_ref
-from repro.kernels.splitk import (lse_merge, paged_decode_attention_splitk,
-                                  paged_verify_attention_splitk)
+from repro.kernels.splitk import lse_merge, paged_verify_attention_splitk
 from _hyputil import given, hyp as _hyp, settings, st
 
 NEG = -1e30
@@ -25,7 +23,8 @@ def _paged_setup(B, g, hd, bs, nbt, n_blocks, pos, seed=0, Sq=0):
     rng = np.random.default_rng(seed)
     tables = np.zeros((B, nbt), np.int32)
     for b in range(B):
-        need = (pos[b] + max(Sq, 1)) // bs + 1
+        # blocks covering positions 0 .. pos + max(Sq, 1) - 1
+        need = -(-(pos[b] + max(Sq, 1)) // bs)
         tables[b, :need] = rng.choice(np.arange(1, n_blocks), size=need,
                                       replace=False)
     return k_pool, v_pool, jnp.asarray(tables), ks[2]
@@ -44,8 +43,9 @@ def test_splitk_decode_matches_ref(dtype, ns):
     q = jax.random.normal(kq, (B, h, hd)).astype(dtype)
     k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
     posj = jnp.asarray(pos, jnp.int32)
-    y = paged_decode_attention_splitk(q, k_pool, v_pool, tables, posj,
-                                      num_splits=ns, interpret=True)
+    y = paged_verify_attention_splitk(q[:, None], k_pool, v_pool, tables,
+                                      posj, jnp.ones((B,), jnp.int32),
+                                      num_splits=ns, interpret=True)[:, 0]
     yr = paged_decode_ref(q, k_pool, v_pool, tables, posj)
     tol = 2e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -80,16 +80,18 @@ def test_splitk_matches_sequential_kernel():
     k_pool, v_pool, tables, kq = _paged_setup(B, g, hd, bs, nbt, 16, pos)
     q = jax.random.normal(kq, (B, h, hd))
     posj = jnp.asarray(pos, jnp.int32)
-    y_seq = paged_decode_attention(q, k_pool, v_pool, tables, posj,
-                                   interpret=True)
-    y_spl = paged_decode_attention_splitk(q, k_pool, v_pool, tables, posj,
-                                          num_splits=4, interpret=True)
+    ones = jnp.ones((B,), jnp.int32)
+    y_seq = paged_attention(q[:, None], k_pool, v_pool, tables, posj, ones,
+                            interpret=True)
+    y_spl = paged_verify_attention_splitk(q[:, None], k_pool, v_pool, tables,
+                                          posj, ones, num_splits=4,
+                                          interpret=True)
     np.testing.assert_allclose(np.asarray(y_spl), np.asarray(y_seq),
                                rtol=2e-5, atol=2e-5)
     lens = jnp.asarray([3, 1], jnp.int32)
     qv = jax.random.normal(kq, (B, 4, h, hd))
-    yv_seq = paged_verify_attention(qv, k_pool, v_pool, tables, posj, lens,
-                                    interpret=True)
+    yv_seq = paged_attention(qv, k_pool, v_pool, tables, posj, lens,
+                             interpret=True)
     yv_spl = paged_verify_attention_splitk(qv, k_pool, v_pool, tables, posj,
                                            lens, num_splits=3, interpret=True)
     valid = np.asarray(lens)[:, None] > np.arange(4)[None, :]
@@ -181,8 +183,9 @@ def test_splitk_kernel_property(B, g, ns, seed, bf16):
     q = jax.random.normal(kq, (B, h, hd)).astype(dtype)
     k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
     posj = jnp.asarray(pos, jnp.int32)
-    y = paged_decode_attention_splitk(q, k_pool, v_pool, tables, posj,
-                                      num_splits=ns, interpret=True)
+    y = paged_verify_attention_splitk(q[:, None], k_pool, v_pool, tables,
+                                      posj, jnp.ones((B,), jnp.int32),
+                                      num_splits=ns, interpret=True)[:, 0]
     yr = paged_decode_ref(q, k_pool, v_pool, tables, posj)
     tol = 2e-5 if not bf16 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -348,8 +351,8 @@ def test_model_decode_bucket_splitk_flag(monkeypatch):
     ref = _drive_decode(cfg, params, toks, tbl, B, S)
     try:
         # key: (cfg.hd, block_size=pool bs (init_paged_cache -> 8), nbt=4,
-        # bh = B * n_heads); ns=3 exercises the non-divisor path in-model
-        autotune.put_config((cfg.hd, 8, 4, B * cfg.n_heads),
+        # bh = B * n_kv_heads); ns=3 exercises the non-divisor path in-model
+        autotune.put_config((cfg.hd, 8, 4, B * cfg.n_kv_heads),
                             AttnConfig(512, 3))
         monkeypatch.setenv("REPRO_PAGED_ATTN_KERNEL", "splitk-interpret")
         got = _drive_decode(cfg, params, toks, tbl, B, S)
@@ -389,7 +392,7 @@ def test_model_verify_bucket_splitk_flag(monkeypatch):
     monkeypatch.delenv("REPRO_PAGED_ATTN_KERNEL", raising=False)
     ref = drive()
     try:
-        autotune.put_config((cfg.hd, 8, 4, B * cfg.n_heads),
+        autotune.put_config((cfg.hd, 8, 4, B * cfg.n_kv_heads),
                             AttnConfig(512, 2))
         monkeypatch.setenv("REPRO_PAGED_ATTN_KERNEL", "splitk-interpret")
         got = drive()
@@ -397,6 +400,30 @@ def test_model_verify_bucket_splitk_flag(monkeypatch):
         autotune.clear_table()
     valid = lens[:, None] > np.arange(k + 1)[None, :]
     np.testing.assert_allclose(got[valid], ref[valid], rtol=2e-4, atol=2e-4)
+
+
+def test_model_splitk_keys_autotune_on_grid_cells(monkeypatch):
+    """The split count is chosen for the grid that runs: one cell per
+    (request, KV head), so the shape key's ``bh`` is B * n_kv_heads."""
+    from repro.configs import get_reduced
+    from repro.models.schema import init_params
+    cfg = get_reduced("llama3-8b")
+    assert cfg.n_kv_heads < cfg.n_heads          # GQA: the two keys differ
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, S = 2, 10
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 1), 0,
+                              cfg.vocab)
+    tbl = jnp.asarray(np.array([[3, 1, 7, 5], [2, 6, 4, 8]], np.int32))
+    keys = []
+
+    def spy(*key):
+        keys.append(key)
+        return AttnConfig(512, 2)
+
+    monkeypatch.setattr(autotune, "choose", spy)
+    monkeypatch.setenv("REPRO_PAGED_ATTN_KERNEL", "splitk-interpret")
+    _drive_decode(cfg, params, toks, tbl, B, S)
+    assert keys and set(keys) == {(cfg.hd, 8, 4, B * cfg.n_kv_heads)}
 
 
 # ------------------------------------------------------------------- engine
