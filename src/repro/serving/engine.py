@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import flow
 from repro.core.unified import make_apply_step, make_forward_step, make_grad_step
 from repro.core.virtualization import MixedLoraModel
@@ -100,6 +101,32 @@ class EngineConfig:
     #                                   hence an explicit opt-in
 
 
+def _step_attrs(kind: str):
+    """The dispatch span's attributes: the step's kind and each segment's
+    bucket rows."""
+    def attrs(base, bank, scale, batch, cache):
+        return dict(kind=kind, **{
+            f"{seg}_rows": getattr(batch, seg).tokens.shape[0]
+            for seg in ("ft", "pf", "dec")
+            if getattr(batch, seg) is not None})
+    return attrs
+
+
+@dataclasses.dataclass
+class _Plan:
+    """One tick's work as planned on the host: what ``_step`` runs and
+    ``_scatter`` reads back."""
+    decision: object
+    ft_rows: List[flow.FTRow]
+    pf_reqs: List[flow.PFReq]
+    chunks: List[Tuple[Request, int, bool]]  # (request, tokens, final?)
+    Sd: int                                  # decode chunk width
+    drafts: Dict[int, np.ndarray]            # slot -> speculative drafts
+    planned: frozenset                       # slots with a decode row
+    batch: UnifiedBatch
+    cache: object
+
+
 class UnifiedEngine:
     def __init__(self, model: MixedLoraModel, ecfg: Optional[EngineConfig] = None):
         self.model = model
@@ -153,8 +180,16 @@ class UnifiedEngine:
         # match (the "decision hit rate")
         self.swap_decisions: List[dict] = []
 
-        self.forward_step = make_forward_step(self.cfg, attn_chunk=e.attn_chunk)
-        self.grad_step = make_grad_step(self.cfg, attn_chunk=e.attn_chunk)
+        # the dispatch span opens inside the stored callables, so a caller
+        # that wraps them (and blocks) still sees the enqueue alone
+        self.forward_step = spans.wrap(
+            "serve.dispatch",
+            make_forward_step(self.cfg, attn_chunk=e.attn_chunk),
+            _step_attrs("fwd"))
+        self.grad_step = spans.wrap(
+            "serve.dispatch",
+            make_grad_step(self.cfg, attn_chunk=e.attn_chunk),
+            _step_attrs("grad"))
         self.apply_step = make_apply_step(e.opt)
         self.opt_state = adamw_init(model.store.bank,
                                     model.store.lcfg.n_slots)
@@ -238,6 +273,28 @@ class UnifiedEngine:
     # ------------------------------------------------------------------
     def tick(self) -> bool:
         """One scheduling + execution round; returns False when idle."""
+        spans.follow_profiler()
+        with spans.span("serve.tick") as sp:
+            with spans.span("serve.plan"):
+                p = self._plan()
+            if p is None:
+                # idle: jump to next arrival if replaying a trace
+                if self.future:
+                    self.clock.advance_to(self.future[0].arrival)
+                    return True
+                return False
+            if sp:
+                sp.set(kind="grad" if p.ft_rows else "fwd",
+                       **self._kv_blocks())
+            out, grads = self._step(p)
+            with spans.span("serve.scatter"):
+                self._scatter(p, out, grads)
+        return True
+
+    def _plan(self) -> Optional[_Plan]:
+        """The tick's host side up to its assembled batch: arrivals, the
+        unservable sweep, the scheduler's decision, trainer rows,
+        admission, decode growth and preemption.  None when idle."""
         self._pull_arrivals()
         e = self.ecfg
         # prefill rows this tick: continuing partial-prefill chunks first
@@ -297,45 +354,8 @@ class UnifiedEngine:
                     self._drop_swap(r)
                     self.waiting.remove(r)
                     self.finished.append(r)
-            suffix_fn = None
-            if self.suffix_prefill:
-                suffix_fn = lambda r: r.prompt_len - self._resident_tokens(r)
-            decision = self.sched.decide(
-                self.waiting, len(self.active) + len(self.prefilling),
-                self.cachemgr.n_free,
-                e.pf_capacity, self.trainers_pending(),
-                # index-held blocks are sheddable inside try_admit, so the
-                # gate must count them as available
-                free_blocks=(self.cachemgr.free_blocks
-                             + self.cachemgr.reclaimable_blocks),
-                total_blocks=self.cachemgr.total_blocks,
-                block_size=self.cachemgr.block_size, s_max=e.s_max,
-                need_fn=lambda r: self.cachemgr.fresh_need(
-                    r.prompt_len, r.remaining_new, r.prompt, r.adapter,
-                    headroom=self._headroom_for(r), keys=self._keys_of(r),
-                    shareable=r.aux_embed is None),
-                spec_headroom=self.spec_headroom,
-                pf_rows_used=len(pf_reqs), pf_token_budget=budget_left,
-                suffix_fn=suffix_fn, chunked=bool(self.chunk_budget),
-                # actually-lent debt fraction: the preemption precursor
-                # that makes fine-tuning yield before inference is evicted
-                lent_frac=(self.cachemgr.lent_blocks
-                           / max(self.cachemgr.reserved_debt, 1)),
-                # prefix-aware admission: prefer requests whose prompt heads
-                # are index-resident (their prefill is mostly free), bounded
-                # by the fairness ramp so cold requests cannot starve
-                probe_fn=(self._resident_tokens if self.hash_dedup
-                          else None),
-                # adapter-residency-aware admission: only under unified
-                # paging — the static-bank baseline keeps the pre-paging
-                # ordering byte-for-byte
-                adapter_fn=((lambda r: self.model.store.is_resident(
-                    r.adapter)) if self.adapter_paging else None),
-                now=self.clock.now())
-        else:
-            decision = self.sched.decide(self.waiting, len(self.active),
-                                         self.cachemgr.n_free, e.pf_capacity,
-                                         self.trainers_pending())
+        with spans.span("serve.decide"):
+            decision = self._decide(pf_reqs, budget_left)
 
         # fine-tuning rows (round-robin over trainers)
         ft_rows: List[flow.FTRow] = []
@@ -371,12 +391,20 @@ class UnifiedEngine:
             except RuntimeError:
                 deferred.add(name)     # bank/pool saturated this tick
 
-        try:
-            self._admit_loop(decision, pf_reqs, chunks, budget_left,
-                             resolved, unknown, deferred, _resolve)
-        finally:
-            for name in resolved:
-                self.model.store.release(name)
+        with spans.span("serve.admit") as sp:
+            try:
+                admitted = self._admit_loop(decision, pf_reqs, chunks,
+                                            budget_left, resolved, unknown,
+                                            deferred, _resolve)
+            finally:
+                for name in resolved:
+                    self.model.store.release(name)
+            if sp:
+                first = [r for r, new in admitted if new]
+                sp.set(rid=[r.rid for r, _ in admitted],
+                       admissions=len(first),
+                       queue_wait_s=sum(r.t_admit - r.arrival
+                                        for r in first))
 
         # decode / verify bucket (static: full table when any request is
         # active; chunk width 1 + k_max whenever speculation is on, so the
@@ -457,15 +485,22 @@ class UnifiedEngine:
             dec_tables = None
 
         if not ft_rows and not pf_reqs and not use_dec:
-            # idle: jump to next arrival if replaying a trace
-            if self.future:
-                self.clock.advance_to(self.future[0].arrival)
-                return True
-            return False
+            return None
 
-        batch = flow.assemble(ft_rows, pf_reqs, dec_tokens, dec_pos,
-                              dec_slots, e.flow, dec_tables=dec_tables,
-                              dec_lens=dec_lens)
+        with spans.span("serve.assemble") as sp:
+            batch = flow.assemble(ft_rows, pf_reqs, dec_tokens, dec_pos,
+                                  dec_slots, e.flow, dec_tables=dec_tables,
+                                  dec_lens=dec_lens)
+            if sp:
+                # real tokens against the bucket positions they were
+                # padded to
+                sp.set(step_tokens=(sum(len(r.tokens) for r in ft_rows)
+                                    + sum(len(q.tokens) for q in pf_reqs)
+                                    + sum(1 + len(p[3]) for p in plans)),
+                       step_positions=sum(
+                           s.tokens.size for s in (batch.ft, batch.pf,
+                                                   batch.dec)
+                           if s is not None))
         # chunked-prefill SLO invariant, checked on the ASSEMBLED batch: a
         # step that runs prefill while requests are mid-decode must carry
         # their decode bucket — any future path that builds a prefill step
@@ -473,22 +508,87 @@ class UnifiedEngine:
         if pf_reqs and self.active and batch.dec is None:
             self.metrics.starved_ticks += 1
         cache = self.cachemgr.step_cache() if (pf_reqs or use_dec) else None
+        return _Plan(decision, ft_rows, pf_reqs, chunks, Sd, drafts, planned,
+                     batch, cache)
 
+    def _kv_blocks(self) -> Dict[str, int]:
+        """The blocks the live requests' tables hold (shared ones once),
+        and those plus the reservations they may still grow into."""
+        if not self.paged:
+            return {}
+        used = len(set().union(*self.cachemgr.tables.values()))
+        return dict(kv_blocks_used=used,
+                    kv_blocks_reserved=used + self.cachemgr.reserved_debt)
+
+    def _decide(self, pf_reqs: List[flow.PFReq], budget_left: Optional[int]):
+        e = self.ecfg
+        if not self.paged:
+            return self.sched.decide(self.waiting, len(self.active),
+                                     self.cachemgr.n_free, e.pf_capacity,
+                                     self.trainers_pending())
+        suffix_fn = None
+        if self.suffix_prefill:
+            suffix_fn = lambda r: r.prompt_len - self._resident_tokens(r)
+        return self.sched.decide(
+            self.waiting, len(self.active) + len(self.prefilling),
+            self.cachemgr.n_free,
+            e.pf_capacity, self.trainers_pending(),
+            # index-held blocks are sheddable inside try_admit, so the
+            # gate must count them as available
+            free_blocks=(self.cachemgr.free_blocks
+                         + self.cachemgr.reclaimable_blocks),
+            total_blocks=self.cachemgr.total_blocks,
+            block_size=self.cachemgr.block_size, s_max=e.s_max,
+            need_fn=lambda r: self.cachemgr.fresh_need(
+                r.prompt_len, r.remaining_new, r.prompt, r.adapter,
+                headroom=self._headroom_for(r), keys=self._keys_of(r),
+                shareable=r.aux_embed is None),
+            spec_headroom=self.spec_headroom,
+            pf_rows_used=len(pf_reqs), pf_token_budget=budget_left,
+            suffix_fn=suffix_fn, chunked=bool(self.chunk_budget),
+            # actually-lent debt fraction: the preemption precursor
+            # that makes fine-tuning yield before inference is evicted
+            lent_frac=(self.cachemgr.lent_blocks
+                       / max(self.cachemgr.reserved_debt, 1)),
+            # prefix-aware admission: prefer requests whose prompt heads
+            # are index-resident (their prefill is mostly free), bounded
+            # by the fairness ramp so cold requests cannot starve
+            probe_fn=(self._resident_tokens if self.hash_dedup
+                      else None),
+            # adapter-residency-aware admission: only under unified
+            # paging — the static-bank baseline keeps the pre-paging
+            # ordering byte-for-byte
+            adapter_fn=((lambda r: self.model.store.is_resident(
+                r.adapter)) if self.adapter_paging else None),
+            now=self.clock.now())
+
+    def _step(self, p: _Plan):
+        """Run the planned batch's step and wait for it: (out, grads)."""
         store = self.model.store
-        if ft_rows:
+        if p.ft_rows:
             res = self.grad_step(self.model.base, store.bank, store.scale,
-                                 batch, cache)
+                                 p.batch, p.cache)
             out, grads = res.out, res.grads
         else:
             out = self.forward_step(self.model.base, store.bank, store.scale,
-                                    batch, cache)
+                                    p.batch, p.cache)
             grads = None
         # the ONE deliberate step barrier: the clock charges a finished
         # step, and the scatter below needs its logits anyway
-        jax.block_until_ready(  # reprolint: sync-point
-            out.dec_logits if out.dec_logits is not None
-            else (out.pf_logits if out.pf_logits is not None
-                  else out.ft_loss_sum))
+        with spans.span("serve.wait"):
+            jax.block_until_ready(  # reprolint: sync-point
+                out.dec_logits if out.dec_logits is not None
+                else (out.pf_logits if out.pf_logits is not None
+                      else out.ft_loss_sum))
+        return out, grads
+
+    def _scatter(self, p: _Plan, out, grads):
+        """Everything after the step's barrier: the clock's charge, tokens
+        to requests, losses to trainers, and the metrics."""
+        e = self.ecfg
+        store = self.model.store
+        chunks, ft_rows, drafts = p.chunks, p.ft_rows, p.drafts
+        use_dec = bool(p.planned)
 
         # ---- time accounting (suffix tokens only: skipped prefix spans
         # cost nothing, which is the whole point of the reuse) ----
@@ -523,9 +623,10 @@ class UnifiedEngine:
         # ---- scatter results back ----
         if out.cache is not None:
             self.cachemgr.update(out.cache)
-        if pf_reqs:
+        if p.pf_reqs:
             # scheduling reads the sampled token: a required sync boundary
-            pf_logits = np.asarray(out.pf_logits)  # reprolint: sync-point
+            with spans.span("serve.readback", what="pf_logits"):
+                pf_logits = np.asarray(out.pf_logits)  # reprolint: sync-point
             assignments, lengths = [], []
             finals: List[Request] = []
             for i, (r, take, final) in enumerate(chunks):
@@ -572,11 +673,12 @@ class UnifiedEngine:
                 self._maybe_finish(r, now)
         if use_dec:
             # argmax/accept decisions drive the next tick's inputs
-            dec_logits = np.asarray(out.dec_logits)  # reprolint: sync-point
+            with spans.span("serve.readback", what="dec_logits"):
+                dec_logits = np.asarray(out.dec_logits)  # reprolint: sync-point
             for slot, r in list(self.active.items()):
-                if r.state is not State.DECODE or slot not in planned:
+                if r.state is not State.DECODE or slot not in p.planned:
                     continue    # just (re-)prefilled this tick: no dec row
-                if Sd > 1:
+                if p.Sd > 1:
                     self._scatter_verify(slot, r, dec_logits[slot],
                                          drafts.get(slot), now)
                 else:
@@ -593,8 +695,9 @@ class UnifiedEngine:
 
         if ft_rows:
             # per-trainer loss bookkeeping happens on host once per step
-            losses = np.asarray(out.ft_loss_sum)  # reprolint: sync-point
-            counts = np.asarray(out.ft_tok_count)  # reprolint: sync-point
+            with spans.span("serve.readback", what="ft_loss"):
+                losses = np.asarray(out.ft_loss_sum)  # reprolint: sync-point
+                counts = np.asarray(out.ft_tok_count)  # reprolint: sync-point
             per_row = losses / np.maximum(counts, 1.0)
             self.grad_accum = tree_add(self.grad_accum, grads)
             by_trainer: Dict[str, List] = {}
@@ -618,7 +721,7 @@ class UnifiedEngine:
 
         self.metrics.steps += 1
         self.metrics.elapsed = self.clock.now()
-        self.metrics.probe_admissions += decision.probe_admissions
+        self.metrics.probe_admissions += p.decision.probe_admissions
         # adapter residency accounting (store-lifetime counters, baselined
         # at engine construction so pre-serving loads are not reported)
         self.metrics.adapter_swap_ins = store.swap_ins - self._swaps_base[0]
@@ -641,12 +744,10 @@ class UnifiedEngine:
                 self.metrics.kv_restore_bytes = m.kv_restore_bytes
                 self.metrics.kv_demotions = m.kv_demotions
                 self.metrics.kv_rehydrated_blocks = m.kv_rehydrations
-                self.metrics.host_bytes_used = hp.used_bytes
                 self.metrics.host_bytes_peak = hp.peak_used_bytes
             if self.adapter_paging:
                 self.metrics.adapter_blocks_resident = \
                     self.cachemgr.adapter_blocks_resident
-        return True
 
     # ------------------------------------------------------- admission body
     def _admit_loop(self, decision, pf_reqs: List[flow.PFReq],
@@ -657,8 +758,10 @@ class UnifiedEngine:
         """Admission body of ``tick``, split out so the per-tick adapter
         holds can wrap it in try/finally.  Appends to ``pf_reqs``/``chunks``
         in place; ``budget_left`` is the remaining chunked-prefill token
-        budget this tick (None = unchunked)."""
+        budget this tick (None = unchunked).  Returns the admitted
+        requests, each with whether this was its first admission."""
         e = self.ecfg
+        admitted: List[Tuple[Request, bool]] = []
         for r in decision.admit:
             if len(pf_reqs) >= e.pf_capacity:
                 break
@@ -717,6 +820,11 @@ class UnifiedEngine:
                 r.adapter_retained = True
             r.dec_slot = slot
             r.state = State.PREFILL
+            # queue wait ends at the first admission; a preempted
+            # request's re-admission is not a new wait
+            admitted.append((r, r.t_admit is None))
+            if r.t_admit is None:
+                r.t_admit = self.clock.now()
             if self.spec:
                 kind = ("suffix" if (self.spec.drafter == "suffix"
                                      and r.draft_suffix is not None)
@@ -766,6 +874,7 @@ class UnifiedEngine:
                     block_table=(self.cachemgr.write_table_of(slot)
                                  if self.paged else None)))
                 chunks.append((r, r.prompt_len, True))
+        return admitted
 
     # ---------------------------------------------- preemption (over-admit)
     def _grow_or_preempt(self, slot: int, r: Request, L: int, n: int,
@@ -922,17 +1031,19 @@ class UnifiedEngine:
         self._maybe_finish(r, now)
 
     def _apply_trainer(self, tr: MixedLoraTrainer):
-        store = self.model.store
-        mask = store.slot_mask([tr.name])
-        new_bank, self.opt_state = self.apply_step(self.grad_accum,
-                                                   self.opt_state,
-                                                   store.bank, mask)
-        store.set_bank(new_bank)
-        # the bank slot now holds newer weights than the host archive /
-        # pool payload — sync happens lazily at the next shed or eviction
-        store.mark_dirty(tr.name)
-        inv = 1.0 - mask
-        self.grad_accum = tree_mask_slots(self.grad_accum, inv)
+        with spans.span("serve.apply", trainer=tr.name):
+            store = self.model.store
+            mask = store.slot_mask([tr.name])
+            new_bank, self.opt_state = self.apply_step(self.grad_accum,
+                                                       self.opt_state,
+                                                       store.bank, mask)
+            store.set_bank(new_bank)
+            # the bank slot now holds newer weights than the host archive /
+            # pool payload — sync happens lazily at the next shed or
+            # eviction
+            store.mark_dirty(tr.name)
+            inv = 1.0 - mask
+            self.grad_accum = tree_mask_slots(self.grad_accum, inv)
 
     def _maybe_finish(self, r: Request, now: float):
         done_len = len(r.output) >= r.max_new_tokens

@@ -52,6 +52,7 @@ class Request:
 
     state: State = State.WAITING
     output: List[int] = dataclasses.field(default_factory=list)
+    t_admit: Optional[float] = None    # first admission (queue wait ends)
     t_first_token: Optional[float] = None
     t_finish: Optional[float] = None
     token_times: List[float] = dataclasses.field(default_factory=list)

@@ -123,7 +123,6 @@ class Metrics:
     #                              beyond what index adoption already covered
     kv_demotions: int = 0        # shed index blocks captured to the host tier
     kv_rehydrated_blocks: int = 0  # demoted blocks re-published on demand
-    host_bytes_used: int = 0     # gauge: host pool bytes at last step
     host_bytes_peak: int = 0     # high-water mark of host pool residency
 
     @property
