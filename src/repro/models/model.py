@@ -212,15 +212,42 @@ def abstract_cache(cfg: ModelConfig, n_rows: int, s_max: int, dtype=None):
     return tree
 
 
-# -- paged pool access (block tables are null-padded with 0; block 0 is the
-#    reserved garbage block, and invalid positions are masked by k_valid) ----
+# -- paged pool access.  Every cache leaf is stacked over layers; a pool leaf
+#    is [L, nb, bs, ...].  The helpers take the stacked leaf and the layer
+#    index ``idx`` and address that layer's blocks in place, through the flat
+#    ``[L*nb, bs, ...]`` view (a bitcast on TPU) with the block tables offset
+#    by ``idx * nb``: the layer is never sliced out and written back, which
+#    would move its whole pool twice per layer.  Tables are null-padded with
+#    0, so padding entries land on block ``idx * nb``, the layer's reserved
+#    garbage block; invalid positions are masked by k_valid. -------------
 
-def _paged_write_prompt(pool: jax.Array, xh: jax.Array,
-                        tables: jax.Array) -> jax.Array:
-    """Scatter prefill writes ``[Bp, Sp, ...]`` into pool blocks via tables
-    ``[Bp, nbt]``; positions beyond ``nbt * block_size`` are dropped (they
-    are padding beyond the context limit)."""
-    bs = pool.shape[1]
+def _cache_value(x: jax.Array, dtype) -> jax.Array:
+    """What a cache write stores.  No derivative flows through the cache,
+    which spares the grad step the scatters' derivative (a select over the
+    whole pool per write).  The fine-tune loss reads fine-tune rows, which
+    attend to their own K/V and never to the cache, so its gradient is
+    unchanged; a router's balance loss reads every row, and loses the part
+    of its gradient that would pass through the inference rows' freshly
+    written K/V."""
+    return jax.lax.stop_gradient(x).astype(dtype)
+
+
+def _flat_pool(pool: jax.Array, tables: jax.Array,
+               idx: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(``[L*nb, bs, ...]`` view of the stacked pool, tables clamped and
+    offset to layer ``idx``'s blocks)."""
+    return (pool.reshape(-1, *pool.shape[2:]),
+            jnp.maximum(tables, 0) + idx * pool.shape[1])
+
+
+def _paged_write_prompt(pool: jax.Array, xh: jax.Array, tables: jax.Array,
+                        idx: jax.Array) -> jax.Array:
+    """Scatter prefill writes ``[Bp, Sp, ...]`` into layer ``idx``'s blocks
+    of the stacked pool via tables ``[Bp, nbt]``; positions beyond
+    ``nbt * block_size`` are dropped (they are padding beyond the context
+    limit)."""
+    flat, tbl = _flat_pool(pool, tables, idx)
+    bs = pool.shape[2]
     Bp, Sp = xh.shape[:2]
     nbp = min(-(-Sp // bs), tables.shape[1])
     Lp = nbp * bs
@@ -229,58 +256,64 @@ def _paged_write_prompt(pool: jax.Array, xh: jax.Array,
     else:
         xh = xh[:, :Lp]
     xb = xh.reshape(Bp, nbp, bs, *xh.shape[2:])
-    tbl = jnp.maximum(tables[:, :nbp], 0)
-    return pool.at[tbl].set(xb.astype(pool.dtype))
-
-
-def _paged_write_token(pool: jax.Array, xh: jax.Array, tables: jax.Array,
-                       pos: jax.Array) -> jax.Array:
-    """Write one decode token per row ``[Bd, ...]`` at its position."""
-    bs = pool.shape[1]
-    tbl = jnp.maximum(tables, 0)
-    rows = jnp.arange(tbl.shape[0])
-    bid = tbl[rows, jnp.clip(pos // bs, 0, tbl.shape[1] - 1)]
-    return pool.at[bid, pos % bs].set(xh.astype(pool.dtype))
+    flat = flat.at[tbl[:, :nbp]].set(_cache_value(xb, pool.dtype))
+    return flat.reshape(pool.shape)
 
 
 def _paged_write_chunk(pool: jax.Array, xh: jax.Array, tables: jax.Array,
-                       pos: jax.Array, length: jax.Array) -> jax.Array:
-    """Scatter a verify chunk ``[Bd, Sd, ...]`` into pool blocks: row ``b``'s
-    token ``j`` lands at position ``pos[b] + j``; positions at or beyond
-    ``length[b]`` (padding / unfilled draft slots) are redirected to the null
-    block so they cannot corrupt live cache state."""
-    bs = pool.shape[1]
+                       idx: jax.Array, pos: jax.Array,
+                       length: jax.Array) -> jax.Array:
+    """Scatter a chunk ``[Bd, Sd, ...]`` (a decode token, a verify chunk or
+    a suffix prefill) into layer ``idx``'s blocks of the stacked pool: row
+    ``b``'s token ``j`` lands at position ``pos[b] + j``; positions at or
+    beyond ``length[b]`` (padding / unfilled draft slots) are redirected to
+    the layer's null block so they cannot corrupt live cache state."""
+    flat, tbl = _flat_pool(pool, tables, idx)
+    bs = pool.shape[2]
     Bd, Sd = xh.shape[:2]
-    tbl = jnp.maximum(tables, 0)
     j = jnp.arange(Sd, dtype=jnp.int32)[None, :]
     p = pos[:, None].astype(jnp.int32) + j                     # [Bd, Sd]
     valid = j < length[:, None]
     bi = jnp.clip(p // bs, 0, tbl.shape[1] - 1)
-    bid = jnp.where(valid, jnp.take_along_axis(tbl, bi, axis=1), 0)
-    flat = xh.reshape(Bd * Sd, *xh.shape[2:])
-    return pool.at[bid.reshape(-1), (p % bs).reshape(-1)].set(
-        flat.astype(pool.dtype))
+    bid = jnp.where(valid, jnp.take_along_axis(tbl, bi, axis=1),
+                    idx * pool.shape[1])
+    xf = xh.reshape(Bd * Sd, *xh.shape[2:])
+    flat = flat.at[bid.reshape(-1), (p % bs).reshape(-1)].set(
+        _cache_value(xf, pool.dtype))
+    return flat.reshape(pool.shape)
 
 
-def _paged_view(pool: jax.Array, tables: jax.Array) -> jax.Array:
-    """Gather per-request contiguous K/V views ``[Bd, nbt*bs, ...]`` — the
-    jnp reference of what kernels.decode_attn.paged_attention streams
+def _paged_view(pool: jax.Array, tables: jax.Array,
+                idx: jax.Array) -> jax.Array:
+    """Gather per-request contiguous views ``[Bd, nbt*bs, ...]`` of layer
+    ``idx``'s blocks, read from the stacked pool in place — the jnp
+    reference of what kernels.decode_attn.paged_attention streams
     block-by-block without materializing."""
-    tbl = jnp.maximum(tables, 0)
+    flat, tbl = _flat_pool(pool, tables, idx)
     Bd, nbt = tbl.shape
-    v = pool[tbl]
-    return v.reshape(Bd, nbt * pool.shape[1], *pool.shape[2:])
+    return flat[tbl].reshape(Bd, nbt * pool.shape[2], *pool.shape[3:])
 
 
-def _paged_dec_mask(tables: jax.Array, block_size: int,
-                    pos: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """(k_pos, k_valid) over the gathered view: positions are the natural
-    0..nbt*bs range, valid through the current token."""
-    Bd, nbt = tables.shape
-    j = jnp.arange(nbt * block_size, dtype=jnp.int32)[None, :]
-    k_pos = jnp.broadcast_to(j, (Bd, nbt * block_size))
-    k_valid = j <= pos[:, None]
-    return k_pos, k_valid
+def _row_write_prompt(c: jax.Array, xh: jax.Array, idx: jax.Array,
+                      row0: int) -> jax.Array:
+    """Write prefill rows ``[Bp, Sp, ...]`` at rows ``row0 ..`` of layer
+    ``idx`` of a stacked dense-row cache ``[L, n_rows, sc, ...]``; a rolling
+    (sliding-window) buffer keeps the last ``sc`` positions."""
+    Bp, Sp = xh.shape[:2]
+    sc = c.shape[2]
+    if Sp <= sc:
+        return c.at[idx, row0:row0 + Bp, :Sp].set(_cache_value(xh, c.dtype))
+    rows = row0 + jnp.arange(Bp)[:, None]
+    sl = (jnp.arange(Sp - sc, Sp) % sc)[None, :]
+    return c.at[idx, rows, sl].set(_cache_value(xh[:, -sc:], c.dtype))
+
+
+def _row_write_token(c: jax.Array, xh: jax.Array, idx: jax.Array,
+                     dpos: jax.Array) -> jax.Array:
+    """Write decode chunks ``[Bd, Sd, ...]`` of rows ``0 .. Bd`` at slots
+    ``dpos % sc`` of layer ``idx`` of a stacked dense-row cache."""
+    rows = jnp.arange(xh.shape[0])[:, None]
+    return c.at[idx, rows, dpos % c.shape[2]].set(_cache_value(xh, c.dtype))
 
 
 def _paged_chunk_mask(tables: jax.Array, block_size: int, pos: jax.Array,
@@ -345,8 +378,10 @@ def _rope_heads(x: jax.Array, pos: jax.Array, n: int, theta: float) -> jax.Array
 
 
 def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
-                plan: _Plan, x: jax.Array, cache: Dict,
+                plan: _Plan, x: jax.Array, cache: Dict, idx: jax.Array,
                 attn_chunk: int) -> Tuple[jax.Array, Dict]:
+    """Attention sublayer of layer ``idx``; ``cache`` holds the leaves
+    stacked over layers, read and written at ``idx`` in place."""
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     W = cfg.sliding_window
     xn = L.rms_norm(x, p["ln1"], cfg.rms_eps)
@@ -355,7 +390,8 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
     Bd = plan.Bd
 
     if cfg.mla is not None:
-        out = _mla_apply(cfg, p, lr, plan, xn, cache, new_cache, attn_chunk)
+        out = _mla_apply(cfg, p, lr, plan, xn, cache, new_cache, idx,
+                         attn_chunk)
     else:
         q = dn(xn, p["wq"], p.get("bq"), lr.get("wq"))
         k = dn(xn, p["wk"], p.get("bk"), lr.get("wk"))
@@ -381,26 +417,27 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
                 # positions >= cached_len), then attend over the pooled view
                 # so cached prefix tokens are READ instead of recomputed
                 ck = _paged_write_chunk(new_cache["k"], kh, plan.pf_tables,
-                                        plan.pf_cached, plan.pf.length)
+                                        idx, plan.pf_cached, plan.pf.length)
                 cv = _paged_write_chunk(new_cache["v"], vh, plan.pf_tables,
-                                        plan.pf_cached, plan.pf.length)
+                                        idx, plan.pf_cached, plan.pf.length)
                 new_cache["k"], new_cache["v"] = ck, cv
                 mode = _paged_kernel_mode()
                 if mode:
                     from repro.kernels.decode_attn import paged_attention
+                    kf, tbl = _flat_pool(ck, plan.pf_tables, idx)
+                    vf, _ = _flat_pool(cv, plan.pf_tables, idx)
                     outs[1] = paged_attention(
-                        qh, ck, cv, plan.pf_tables, plan.pf_cached,
-                        plan.pf.length,
+                        qh, kf, vf, tbl, plan.pf_cached, plan.pf.length,
                         interpret=mode.endswith("interpret"))
                 else:
                     k_pos, k_valid = _paged_chunk_mask(
-                        plan.pf_tables, ck.shape[1], plan.pf_cached,
+                        plan.pf_tables, ck.shape[2], plan.pf_cached,
                         plan.pf.length)
                     outs[1] = L.attention(
-                        qh, _paged_view(ck, plan.pf_tables),
-                        _paged_view(cv, plan.pf_tables), q_pos=plan.pf_pos,
-                        k_pos=k_pos, k_valid=k_valid, causal=True, window=0,
-                        chunk=attn_chunk)
+                        qh, _paged_view(ck, plan.pf_tables, idx),
+                        _paged_view(cv, plan.pf_tables, idx),
+                        q_pos=plan.pf_pos, k_pos=k_pos, k_valid=k_valid,
+                        causal=True, window=0, chunk=attn_chunk)
             else:
                 outs[1] = L.attention(qh, kh, vh, q_pos=plan.pf_pos,
                                       k_pos=plan.pf_pos,
@@ -410,18 +447,14 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
             if plan.pf_tables is not None and plan.pf_cached is None:
                 # paged full-prompt prefill: straight into the blocks
                 new_cache["k"] = _paged_write_prompt(new_cache["k"], kh,
-                                                     plan.pf_tables)
+                                                     plan.pf_tables, idx)
                 new_cache["v"] = _paged_write_prompt(new_cache["v"], vh,
-                                                     plan.pf_tables)
+                                                     plan.pf_tables, idx)
             elif plan.pf_tables is None:
-                sc = cache["k"].shape[1]
-                if plan.Sp <= sc:
-                    new_cache["k"] = new_cache["k"].at[Bd:Bd + plan.Bp, :plan.Sp].set(kh)
-                    new_cache["v"] = new_cache["v"].at[Bd:Bd + plan.Bp, :plan.Sp].set(vh)
-                else:             # rolling buffer: keep last sc positions
-                    sl = (jnp.arange(plan.Sp - sc, plan.Sp) % sc)
-                    new_cache["k"] = new_cache["k"].at[Bd:Bd + plan.Bp, sl].set(kh[:, -sc:])
-                    new_cache["v"] = new_cache["v"].at[Bd:Bd + plan.Bp, sl].set(vh[:, -sc:])
+                new_cache["k"] = _row_write_prompt(new_cache["k"], kh, idx,
+                                                   Bd)
+                new_cache["v"] = _row_write_prompt(new_cache["v"], vh, idx,
+                                                   Bd)
         if qd is not None:       # decode / verify: (1 + k)-token chunk
             Sd = plan.Sd
             dpos = plan.dec_qpos                               # [Bd, Sd]
@@ -430,9 +463,9 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
             vh = vd.reshape(plan.Bd, Sd, kv, hd)
             if plan.dec_tables is not None:  # paged: block-table gather
                 ck = _paged_write_chunk(new_cache["k"], kh, plan.dec_tables,
-                                        plan.dec_pos, plan.dec_len)
+                                        idx, plan.dec_pos, plan.dec_len)
                 cv = _paged_write_chunk(new_cache["v"], vh, plan.dec_tables,
-                                        plan.dec_pos, plan.dec_len)
+                                        idx, plan.dec_pos, plan.dec_len)
                 new_cache["k"], new_cache["v"] = ck, cv
                 mode = _paged_kernel_mode()
                 if mode:
@@ -442,43 +475,42 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
                     # table/heuristic picks the fan-out per shape at trace
                     # time.
                     interp = mode.endswith("interpret")
+                    kf, tbl = _flat_pool(ck, plan.dec_tables, idx)
+                    vf, _ = _flat_pool(cv, plan.dec_tables, idx)
                     if mode.startswith("splitk"):
                         from repro.kernels.autotune import choose
                         from repro.kernels.splitk import \
                             paged_verify_attention_splitk
-                        kc = choose(hd, ck.shape[1],
+                        kc = choose(hd, ck.shape[2],
                                     plan.dec_tables.shape[1], plan.Bd * kv)
                         outs[2] = paged_verify_attention_splitk(
-                            qh, ck, cv, plan.dec_tables, plan.dec_pos,
-                            plan.dec_len, num_splits=kc.num_splits,
-                            interpret=interp)
+                            qh, kf, vf, tbl, plan.dec_pos, plan.dec_len,
+                            num_splits=kc.num_splits, interpret=interp)
                     else:
                         from repro.kernels.decode_attn import paged_attention
                         outs[2] = paged_attention(
-                            qh, ck, cv, plan.dec_tables, plan.dec_pos,
-                            plan.dec_len, interpret=interp)
+                            qh, kf, vf, tbl, plan.dec_pos, plan.dec_len,
+                            interpret=interp)
                 else:
                     k_pos, k_valid = _paged_chunk_mask(
-                        plan.dec_tables, ck.shape[1], plan.dec_pos,
+                        plan.dec_tables, ck.shape[2], plan.dec_pos,
                         plan.dec_len)
                     outs[2] = L.attention(
-                        qh, _paged_view(ck, plan.dec_tables),
-                        _paged_view(cv, plan.dec_tables), q_pos=dpos,
+                        qh, _paged_view(ck, plan.dec_tables, idx),
+                        _paged_view(cv, plan.dec_tables, idx), q_pos=dpos,
                         k_pos=k_pos, k_valid=k_valid, causal=True, window=0)
             else:
                 if Sd > 1 and W > 0:
                     raise NotImplementedError(
                         "verify chunks need a non-rolling cache: rolled-back "
                         "draft positions would alias live window slots")
-                sc = cache["k"].shape[1]
-                slot = dpos % sc                               # [Bd, Sd]
-                rows = jnp.arange(plan.Bd)[:, None]
-                ck = new_cache["k"].at[rows, slot].set(kh)
-                cv = new_cache["v"].at[rows, slot].set(vh)
+                sc = cache["k"].shape[2]
+                ck = _row_write_token(new_cache["k"], kh, idx, dpos)
+                cv = _row_write_token(new_cache["v"], vh, idx, dpos)
                 new_cache["k"], new_cache["v"] = ck, cv
                 k_pos, k_valid = _dec_cache_pos(
                     plan.dec_pos + plan.dec_len - 1, sc)
-                outs[2] = L.attention(qh, ck[:Bd], cv[:Bd],
+                outs[2] = L.attention(qh, ck[idx, :Bd], cv[idx, :Bd],
                                       q_pos=dpos, k_pos=k_pos,
                                       k_valid=k_valid, causal=True, window=0)
         out = _merge_flat(plan, *outs)
@@ -486,14 +518,16 @@ def _attn_apply(cfg: ModelConfig, pos_idx: int, p: Dict, lr: Dict,
     x = x + o
 
     if cfg.is_cross_layer(pos_idx):
-        x = _cross_apply(cfg, p, lr, plan, x, cache, new_cache, attn_chunk)
+        x = _cross_apply(cfg, p, lr, plan, x, cache, new_cache, idx,
+                         attn_chunk)
     return x, new_cache
 
 
 def _mla_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
-               xn: jax.Array, cache: Dict, new_cache: Dict,
+               xn: jax.Array, cache: Dict, new_cache: Dict, idx: jax.Array,
                attn_chunk: int = 0) -> jax.Array:
-    """Absorbed-form MLA for all buckets; the cache holds the latent."""
+    """Absorbed-form MLA for all buckets; the cache holds the latent,
+    stacked over layers and addressed at layer ``idx`` in place."""
     m, h = cfg.mla, cfg.n_heads
     dnp, dr, c_rank = m.qk_nope_dim, m.qk_rope_dim, m.kv_lora_rank
     dn = functools.partial(dense, ids=plan.ids, scale_t=plan.scale_t)
@@ -531,14 +565,15 @@ def _mla_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
             # the pooled view so the cached prefix latent is read, not
             # recomputed (same contract as the standard-attention path)
             cc = _paged_write_chunk(new_cache["ckv"], ckv, plan.pf_tables,
-                                    plan.pf_cached, plan.pf.length)
+                                    idx, plan.pf_cached, plan.pf.length)
             ce = _paged_write_chunk(new_cache["kpe"], kpe, plan.pf_tables,
-                                    plan.pf_cached, plan.pf.length)
+                                    idx, plan.pf_cached, plan.pf.length)
             new_cache["ckv"], new_cache["kpe"] = cc, ce
-            k_pos, k_valid = _paged_chunk_mask(plan.pf_tables, cc.shape[1],
+            k_pos, k_valid = _paged_chunk_mask(plan.pf_tables, cc.shape[2],
                                                plan.pf_cached, plan.pf.length)
-            outs[1] = L.mla_attention(qn, qr, _paged_view(cc, plan.pf_tables),
-                                      _paged_view(ce, plan.pf_tables),
+            outs[1] = L.mla_attention(qn, qr,
+                                      _paged_view(cc, plan.pf_tables, idx),
+                                      _paged_view(ce, plan.pf_tables, idx),
                                       p["wuk"], p["wuv"], q_pos=plan.pf_pos,
                                       k_pos=k_pos, k_valid=k_valid,
                                       causal=True, window=0,
@@ -552,18 +587,14 @@ def _mla_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
         if plan.pf_tables is not None and plan.pf_cached is None:
             # paged full-prompt prefill: straight into the blocks
             new_cache["ckv"] = _paged_write_prompt(new_cache["ckv"], ckv,
-                                                   plan.pf_tables)
+                                                   plan.pf_tables, idx)
             new_cache["kpe"] = _paged_write_prompt(new_cache["kpe"], kpe,
-                                                   plan.pf_tables)
+                                                   plan.pf_tables, idx)
         elif plan.pf_tables is None:
-            sc = cache["ckv"].shape[1]
-            if plan.Sp <= sc:
-                new_cache["ckv"] = new_cache["ckv"].at[Bd:Bd + plan.Bp, :plan.Sp].set(ckv)
-                new_cache["kpe"] = new_cache["kpe"].at[Bd:Bd + plan.Bp, :plan.Sp].set(kpe)
-            else:
-                sl = (jnp.arange(plan.Sp - sc, plan.Sp) % sc)
-                new_cache["ckv"] = new_cache["ckv"].at[Bd:Bd + plan.Bp, sl].set(ckv[:, -sc:])
-                new_cache["kpe"] = new_cache["kpe"].at[Bd:Bd + plan.Bp, sl].set(kpe[:, -sc:])
+            new_cache["ckv"] = _row_write_prompt(new_cache["ckv"], ckv, idx,
+                                                 Bd)
+            new_cache["kpe"] = _row_write_prompt(new_cache["kpe"], kpe, idx,
+                                                 Bd)
     if qd is not None:
         Sd = plan.Sd
         dpos = plan.dec_qpos                                   # [Bd, Sd]
@@ -573,14 +604,15 @@ def _mla_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
         kpe = L.rope(kpe[..., None, :], dpos, cfg.rope_theta)[..., 0, :]
         if plan.dec_tables is not None:      # paged: block-table gather
             cc = _paged_write_chunk(new_cache["ckv"], ckv, plan.dec_tables,
-                                    plan.dec_pos, plan.dec_len)
+                                    idx, plan.dec_pos, plan.dec_len)
             ce = _paged_write_chunk(new_cache["kpe"], kpe, plan.dec_tables,
-                                    plan.dec_pos, plan.dec_len)
+                                    idx, plan.dec_pos, plan.dec_len)
             new_cache["ckv"], new_cache["kpe"] = cc, ce
-            k_pos, k_valid = _paged_chunk_mask(plan.dec_tables, cc.shape[1],
+            k_pos, k_valid = _paged_chunk_mask(plan.dec_tables, cc.shape[2],
                                                plan.dec_pos, plan.dec_len)
-            outs[2] = L.mla_attention(qn, qr, _paged_view(cc, plan.dec_tables),
-                                      _paged_view(ce, plan.dec_tables),
+            outs[2] = L.mla_attention(qn, qr,
+                                      _paged_view(cc, plan.dec_tables, idx),
+                                      _paged_view(ce, plan.dec_tables, idx),
                                       p["wuk"], p["wuv"], q_pos=dpos,
                                       k_pos=k_pos, k_valid=k_valid,
                                       causal=True, window=0)
@@ -589,24 +621,24 @@ def _mla_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
                 raise NotImplementedError(
                     "verify chunks need a non-rolling cache: rolled-back "
                     "draft positions would alias live window slots")
-            sc = cache["ckv"].shape[1]
-            slot = dpos % sc
-            rows = jnp.arange(plan.Bd)[:, None]
-            cc = new_cache["ckv"].at[rows, slot].set(ckv)
-            ce = new_cache["kpe"].at[rows, slot].set(kpe)
+            sc = cache["ckv"].shape[2]
+            cc = _row_write_token(new_cache["ckv"], ckv, idx, dpos)
+            ce = _row_write_token(new_cache["kpe"], kpe, idx, dpos)
             new_cache["ckv"], new_cache["kpe"] = cc, ce
             k_pos, k_valid = _dec_cache_pos(plan.dec_pos + plan.dec_len - 1,
                                             sc)
-            outs[2] = L.mla_attention(qn, qr, cc[:Bd], ce[:Bd], p["wuk"],
-                                      p["wuv"], q_pos=dpos, k_pos=k_pos,
-                                      k_valid=k_valid, causal=True, window=0)
+            outs[2] = L.mla_attention(qn, qr, cc[idx, :Bd], ce[idx, :Bd],
+                                      p["wuk"], p["wuv"], q_pos=dpos,
+                                      k_pos=k_pos, k_valid=k_valid,
+                                      causal=True, window=0)
     return _merge_flat(plan, *outs)
 
 
 def _cross_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
-                 x: jax.Array, cache: Dict, new_cache: Dict,
+                 x: jax.Array, cache: Dict, new_cache: Dict, idx: jax.Array,
                  attn_chunk: int = 0) -> jax.Array:
-    """Cross-attention sublayer (VLM image layers / enc-dec decoder)."""
+    """Cross-attention sublayer (VLM image layers / enc-dec decoder); the
+    cross K/V rows are stacked over layers and addressed at ``idx``."""
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     dn = functools.partial(dense, ids=plan.ids, scale_t=plan.scale_t)
     xn = L.rms_norm(x, p["xln"], cfg.rms_eps)
@@ -640,10 +672,12 @@ def _cross_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
         src = plan.pf.aux_embed.astype(x.dtype)
         kx, vx = _kv_from(src)
         outs[1] = _xattn(qp, kx, vx, plan.pf_pos)
-        new_cache["xk"] = new_cache["xk"].at[Bd:Bd + plan.Bp].set(kx)
-        new_cache["xv"] = new_cache["xv"].at[Bd:Bd + plan.Bp].set(vx)
+        for key, val in (("xk", kx), ("xv", vx)):
+            c = new_cache[key]
+            new_cache[key] = c.at[idx, Bd:Bd + plan.Bp].set(
+                _cache_value(val, c.dtype))
     if qd is not None:
-        kx, vx = cache["xk"][:Bd], cache["xv"][:Bd]
+        kx, vx = cache["xk"][idx, :Bd], cache["xv"][idx, :Bd]
         outs[2] = _xattn(qd, kx, vx, plan.dec_qpos)
     o = _merge_flat(plan, *outs)
     o = dn(o, p["xwo"], None, lr.get("xwo"))
@@ -657,7 +691,10 @@ def _cross_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
 # ---------------------------------------------------------------------------
 
 def _mamba_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
-                 x: jax.Array, cache: Dict) -> Tuple[jax.Array, Dict]:
+                 x: jax.Array, cache: Dict,
+                 idx: jax.Array) -> Tuple[jax.Array, Dict]:
+    """Mamba2 sublayer of layer ``idx``; the state rows are stacked over
+    layers and addressed at ``idx`` in place."""
     s = cfg.ssm
     di, nh, hdm = cfg.d_inner, cfg.n_ssm_heads, s.head_dim
     gds = s.n_groups * s.d_state
@@ -704,9 +741,11 @@ def _mamba_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
         y, h_fin, cx_fin, cbc_fin = _seq(xp_, bp, dp_, plan.pf_valid,
                                          None, None, None)
         outs[1] = _gated_out(y, zp, p)
-        new_cache["h"] = new_cache["h"].at[Bd:Bd + plan.Bp].set(h_fin)
-        new_cache["conv_x"] = new_cache["conv_x"].at[Bd:Bd + plan.Bp].set(cx_fin)
-        new_cache["conv_bc"] = new_cache["conv_bc"].at[Bd:Bd + plan.Bp].set(cbc_fin)
+        for key, val in (("h", h_fin), ("conv_x", cx_fin),
+                         ("conv_bc", cbc_fin)):
+            c = new_cache[key]
+            new_cache[key] = c.at[idx, Bd:Bd + plan.Bp].set(
+                _cache_value(val, c.dtype))
     if zd is not None:
         if plan.Sd > 1:
             raise NotImplementedError(
@@ -714,20 +753,22 @@ def _mamba_apply(cfg: ModelConfig, p: Dict, lr: Dict, plan: _Plan,
                 "speculative verify chunks are attention-only")
         B = plan.Bd
         y_x, cx_new = M.causal_conv(xd, p["conv_x"], p["conv_bx"],
-                                    cache["conv_x"][:Bd])
+                                    cache["conv_x"][idx, :Bd])
         y_bc, cbc_new = M.causal_conv(bd, p["conv_bc"], p["conv_bbc"],
-                                      cache["conv_bc"][:Bd])
+                                      cache["conv_bc"][idx, :Bd])
         y_x, y_bc = jax.nn.silu(y_x[:, 0]), jax.nn.silu(y_bc[:, 0])
         xs2 = y_x.reshape(B, nh, hdm)
         b2, c2 = _expand_bc(y_bc, (B,))
         dtv = jax.nn.softplus(dd[:, 0].astype(jnp.float32)
                               + p["dt_bias"].astype(jnp.float32))
-        y, h_new = M.ssd_step(cache["h"][:Bd], xs2, dtv.astype(xs2.dtype),
+        y, h_new = M.ssd_step(cache["h"][idx, :Bd], xs2,
+                              dtv.astype(xs2.dtype),
                               A, b2, c2)
         y = y + p["d_skip"].astype(y.dtype)[None, :, None] * xs2
-        new_cache["h"] = new_cache["h"].at[:Bd].set(h_new)
-        new_cache["conv_x"] = new_cache["conv_x"].at[:Bd].set(cx_new)
-        new_cache["conv_bc"] = new_cache["conv_bc"].at[:Bd].set(cbc_new)
+        for key, val in (("h", h_new), ("conv_x", cx_new),
+                         ("conv_bc", cbc_new)):
+            c = new_cache[key]
+            new_cache[key] = c.at[idx, :Bd].set(_cache_value(val, c.dtype))
         outs[2] = _gated_out(y.reshape(B, 1, di), zd, p)
     y = _merge_flat(plan, *outs)
     o = dense(y, p["out_proj"], None, lr.get("out_proj"),
@@ -850,36 +891,33 @@ def unified_forward(cfg: ModelConfig, params: Dict, batch: UnifiedBatch,
     cache_layers = (cache["layers"] if cache is not None
                     else tuple({} for _ in cfg.pattern))
 
-    # The cache rides in the scan CARRY (updated in place with
-    # dynamic_update_index_in_dim) rather than as scan xs/ys: XLA buffer
-    # assignment then keeps ONE cache buffer alive instead of
-    # double-buffering sliced-in xs against stacked-out ys (which costs an
-    # extra full cache copy of HBM at decode time).
+    # The cache rides in the scan CARRY, stacked over layers, rather than as
+    # scan xs/ys: XLA buffer assignment then keeps ONE cache buffer alive
+    # instead of double-buffering sliced-in xs against stacked-out ys.  Each
+    # sublayer reads and writes its layer's part of the stacked leaves at
+    # ``idx`` in place (a scatter of the step's tokens, a gather of the rows
+    # it reads); slicing the layer out and writing it back would move that
+    # layer's whole pool twice.  Cache writes carry no derivative
+    # (``_cache_value``), so the grad step differentiates no scatter.
     def period(carry, xs):
         xx, aux_acc, cl, idx = carry
         pp, ll = xs
-        cc = jax.tree_util.tree_map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, idx, 0, keepdims=False),
-            cl)
         if quantized:
             pp = quant.dequant_tree(pp, jnp.dtype(cfg.dtype), gather_specs)
-        new_cc = []
+        new_cl = []
         for pos, kind in enumerate(cfg.pattern):
             if kind == "attn":
                 xx, c_out = _attn_apply(cfg, pos, pp[pos], ll[pos], plan, xx,
-                                        cc[pos], attn_chunk)
+                                        cl[pos], idx, attn_chunk)
             else:
                 xx, c_out = _mamba_apply(cfg, pp[pos], ll[pos], plan, xx,
-                                         cc[pos])
+                                         cl[pos], idx)
             xx, aux = _ffn_apply(cfg, pos, pp[pos], ll[pos], plan, xx)
             aux_acc = aux_acc + aux
-            new_cc.append(c_out)
-        cl = jax.tree_util.tree_map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, idx, 0),
-            cl, tuple(new_cc))
+            new_cl.append(c_out)
         if act_constraint is not None:
             xx = act_constraint(xx)
-        return (xx, aux_acc, cl, idx + 1), None
+        return (xx, aux_acc, tuple(new_cl), idx + 1), None
 
     body = jax.checkpoint(period) if remat else period
     (x, aux_loss, new_layers, _), _ = jax.lax.scan(
