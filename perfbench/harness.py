@@ -8,6 +8,10 @@ is a file found by the name ``BENCHMARK.json`` gives it:
 
     perfbench/cells/<cell>.json      engine sizes, fixed rate, limits
     <configs[].file>                 the model, as run (published keys)
+    perfbench/arch/<model_type>.py   the configuration's architecture, by
+                                     the ``model_type`` its file states:
+                                     ``model_config``, ``Reference``,
+                                     ``step_flops``, ``NORMS``
     perfbench/traffic/<mix>.json     the mix's parameters
     perfbench/metrics/<metric>.py    a reader ``read(run, name)``; a name
                                      ``a.b`` falls back to ``a.py``
@@ -21,7 +25,8 @@ import json
 import shutil
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Tuple
+from types import ModuleType
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +51,7 @@ class Cell:
     name: str
     chips: int
     config: Dict          # the configuration file (published keys, as run)
+    arch: ModuleType      # perfbench/arch/<model_type>.py
     traffic: Dict         # the mix's parameters
     setup: Dict           # perfbench/cells/<name>.json
     metrics: List[Dict]   # manifest entries that this cell reports
@@ -66,9 +72,10 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
                 if applies(m)]
                + [dict(m, kind="per_layer") for m in man["per_layer"]
                   if applies(m)])
+    config = json.loads((root / cfg_file).read_text())
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((root / cfg_file).read_text()),
+        name=name, chips=int(w["chips"]), config=config,
+        arch=architecture(config["model_type"], root),
         traffic=json.loads(
             (root / "perfbench" / "traffic" / f"{w['traffic']}.json")
             .read_text()),
@@ -77,19 +84,20 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
         metrics=metrics)
 
 
-def model_config(spec: Dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.configs import ModelConfig
-    d, h = spec["hidden_size"], spec["num_attention_heads"]
-    return ModelConfig(
-        name=spec["name"], arch_type="dense",
-        n_layers=spec["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=spec["num_key_value_heads"],
-        d_ff=spec["intermediate_size"], vocab=spec["vocab_size"],
-        head_dim=d // h, rope_theta=float(spec["rope_theta"]),
-        rms_eps=float(spec["rms_norm_eps"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        dtype=spec["torch_dtype"])
+def architecture(model_type: str, root: Path = REPO) -> ModuleType:
+    """The module ``perfbench/arch/<model_type>.py`` under ``root``."""
+    rel = f"perfbench/arch/{model_type}.py"
+    path = root / rel
+    if not path.is_file():
+        raise FileNotFoundError(f"model_type {model_type!r} has no "
+                                f"architecture module: no {rel}")
+    if path.resolve() == (HERE / "arch" / f"{model_type}.py").resolve():
+        return importlib.import_module(f"perfbench.arch.{model_type}")
+    spec = importlib.util.spec_from_file_location(
+        f"perfbench_arch_{model_type}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(name: str) -> Optional[Callable]:
@@ -204,10 +212,11 @@ class Recorder:
     def stepped(self) -> bool:
         return self._step is not None
 
-    def sizes(self, spec: Dict, r: int):
-        """Each recorded tick's model FLOPs, from the host-side sizes of
-        its batch: trained fine-tune rows, prefill rows (computed, cached)
-        and decode rows with a block table (their context)."""
+    def sizes(self, cell: Cell, r: int):
+        """Each recorded tick's model FLOPs (the architecture's
+        ``step_flops``), from the host-side sizes of its batch: trained
+        fine-tune rows, prefill rows (computed, cached) and decode rows with
+        a block table (their context)."""
         for x in self.ticks:
             if x.batch is None:
                 continue
@@ -220,15 +229,15 @@ class Recorder:
                   if b.pf is not None else [])
             dec = ([int(p) + 1 for p, t in zip(b.dec.pos, b.dec.block_tables)
                     if t.any()] if b.dec is not None else [])
-            x.flops = stats.step_flops(spec, r, ft, pf, dec)
+            x.flops = cell.arch.step_flops(cell.config, r, ft, pf, dec)
             x.batch = None
 
-    def training(self, b1: float) -> Optional[Dict]:
+    def training(self, b1: float, targets: Sequence[str]) -> Optional[Dict]:
         """The trained adapter's first optimizer steps as the program took
         them: ``steps`` (per step, the tokens of its rows), ``losses`` (per
         step, per row), ``grad`` (the first gradient as the optimizer got
-        it, from its first moment) and ``change``; None if the run made
-        fewer steps."""
+        it, from its first moment) and ``change``, over the LoRA
+        ``targets``; None if the run made fewer steps."""
         if len(self.bounds) < self.steps:
             return None
         steps, losses, lo = [], [], 0
@@ -243,8 +252,9 @@ class Recorder:
             losses.append(np.array(ls))
             lo = hi
         grad = ref_mod.leaves(jax.tree_util.tree_map(
-            lambda m: m / (1.0 - b1), self.m1))
-        p0, pn = ref_mod.leaves(self.p0), ref_mod.leaves(self.pn)
+            lambda m: m / (1.0 - b1), self.m1), targets)
+        p0, pn = (ref_mod.leaves(self.p0, targets),
+                  ref_mod.leaves(self.pn, targets))
         return dict(steps=steps, losses=losses, grad=grad,
                     change={k: pn[k] - p0[k] for k in p0})
 
@@ -278,7 +288,8 @@ def makers(cell: Cell, cfg, lcfg, n_adapters: int):
     from repro.models.schema import abstract_params
     lo = cell.config["lora"]
     b_scale = 0.02 * lo["alpha"] / lo["r"]
-    return (weights.maker(abstract_params(cfg), weights.weight_std),
+    return (weights.maker(abstract_params(cfg),
+                          weights.weight_std(cell.arch.NORMS)),
             weights.maker(adapter_shapes(cfg, lcfg, n_adapters),
                           weights.adapter_std(b_scale)))
 
@@ -453,8 +464,8 @@ def compare(cell: Cell, make_params, make_adapters, seed: int,
     adapters = make_adapters(weights.seed_key(seed, 1))
     s_pad = cell.setup["engine"]["s_max"]
     p_pad = cell.traffic["output_tokens"]["max"]
-    ref = ref_mod.Reference(cell.config)
-    ctl = ref_mod.Reference(cell.config, fp8=True) if study else None
+    ref = cell.arch.Reference(cell.config)
+    ctl = cell.arch.Reference(cell.config, fp8=True) if study else None
     out = {}
     if served:
         lg = ref.served_logits(params, adapters, served, s_pad, p_pad)
@@ -542,7 +553,7 @@ def build(cell: Cell, seed: int) -> Built:
     """Weights and adapters drawn on the device by one jitted program each,
     the adapters loaded into the program's store."""
     from repro.core.virtualization import AdapterStore, MixedLoraModel
-    cfg = model_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     names = [f"a{i}" for i in range(cell.traffic["adapters"])] \
         + ([TRAINED] if "trainer" in cell.traffic else [])
     lcfg = lora_config(cell, len(names))
@@ -627,7 +638,7 @@ def serve(cell: Cell, built: Built, seed: int, seconds: float, rate: float,
     n_c1, _ = compiles()
     log(f"window: {w1 - w0:.3f} s, {len(rec.ticks)} ticks, "
         f"{n_c1 - n_c0} compiles")
-    rec.sizes(cell.config, built.lcfg.r)
+    rec.sizes(cell, built.lcfg.r)
     devs = jax.devices()[:cell.chips]
     run = Run(cell=cell, chips=cell.chips,
               peaks=(stats.peaks(devs[0].device_kind)
@@ -639,8 +650,9 @@ def serve(cell: Cell, built: Built, seed: int, seconds: float, rate: float,
     win = Window(run=run, device=device_info(devs),
                  picked=sample(eng, reqs,
                                int(cell.setup["sample_requests"]), seed),
-                 training=(rec.training(eng.ecfg.opt.b1) if built.trains
-                           else None),
+                 training=(rec.training(eng.ecfg.opt.b1,
+                                        built.lcfg.targets)
+                           if built.trains else None),
                  compiles=n_c1 - n_c0,
                  queue_growth=stats.queue_growth(
                      [(x.t1, x.waiting) for x in rec.ticks]))

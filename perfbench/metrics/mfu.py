@@ -1,4 +1,5 @@
-"""Model FLOPs of every step in the window (``stats.step_flops``) over the
+"""Model FLOPs of every step in the window (the architecture's
+``step_flops``) over the
 window times the chips times the chip's bf16 peak."""
 
 

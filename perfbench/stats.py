@@ -1,5 +1,6 @@
 """The benchmark's arithmetic: latency percentiles over the window, the
-paper's SLO, queue growth, model FLOPs of a step, and the table of peaks.
+paper's SLO, queue growth and the table of peaks (a step's model FLOPs are
+its architecture's: ``arch/<model_type>.py``).
 
 ``SLO`` and ``meets_slo`` copy ``serving/slo.py`` (the paper's App. C/D
 limits) so that the yardstick does not move with the program.
@@ -92,56 +93,6 @@ def queue_growth(samples: Sequence[Tuple[float, int]]) -> float:
         return 0.0
     slope = np.polyfit(t - t[0], q, 1)[0]
     return float(slope * (t[-1] - t[0]))
-
-
-# -- model FLOPs --------------------------------------------------------------
-
-def layer_params(spec: Dict) -> int:
-    """Parameters of one decoder layer's linears."""
-    d, h, g = (spec["hidden_size"], spec["num_attention_heads"],
-               spec["num_key_value_heads"])
-    hd, ff = d // h, spec["intermediate_size"]
-    return d * h * hd * 2 + d * g * hd * 2 + 3 * d * ff
-
-
-def lora_params(spec: Dict, r: int) -> int:
-    """Parameters of one layer's rank-``r`` adapter on the seven targets."""
-    d, h, g = (spec["hidden_size"], spec["num_attention_heads"],
-               spec["num_key_value_heads"])
-    hd, ff = d // h, spec["intermediate_size"]
-    dims = [(d, h * hd), (d, g * hd), (d, g * hd), (h * hd, d),
-            (d, ff), (d, ff), (ff, d)]
-    return sum(r * (a + b) for a, b in dims)
-
-
-def step_flops(spec: Dict, r: int, ft_lens: Sequence[int],
-               pf_rows: Sequence[Tuple[int, int]],
-               dec_ctx: Sequence[int]) -> float:
-    """Model FLOPs one unified step needs: 2 per parameter per token through
-    the layers (base and adapter), 4 * heads * head_dim per attended key per
-    layer, and the head where logits are taken (every fine-tune position,
-    the last prompt position of a prefill row, each decode row).  Fine-tune
-    tokens count twice: the forward and the input gradient.  ``pf_rows``
-    holds (computed tokens, cached prefix); ``dec_ctx`` each decode row's
-    context including its new token."""
-    L, d, V = (spec["num_hidden_layers"], spec["hidden_size"],
-               spec["vocab_size"])
-    h = spec["num_attention_heads"]
-    lin = 2.0 * (layer_params(spec) + lora_params(spec, r))
-    att = 4.0 * h * (d // h)
-    head = 2.0 * d * V
-
-    def fwd(tokens, keys, logit_rows):
-        return L * (lin * tokens + att * keys) + head * logit_rows
-
-    total = 0.0
-    for n in ft_lens:
-        total += 2 * fwd(n, n * (n + 1) / 2, n)
-    for n, c in pf_rows:
-        total += fwd(n, n * c + n * (n + 1) / 2, 1)
-    for c in dec_ctx:
-        total += fwd(1, c, 1)
-    return total
 
 
 def peaks(device_kind: str) -> Dict:

@@ -33,7 +33,8 @@ TINY_ENGINE = dict(capacity=4, pf_capacity=2, prefill_chunk=32, s_max=128,
 def tiny(cell_name: str):
     """The named cell of BENCHMARK.json at the tiny size."""
     from perfbench import harness
-    cell = copy.deepcopy(harness.load_cell(cell_name))
+    cell = harness.load_cell(cell_name)
+    cell = copy.deepcopy(cell, {id(cell.arch): cell.arch})
     cell.config.update(TINY_MODEL)
     cell.traffic.update(TINY_MIX)
     if "trainer" in cell.traffic:

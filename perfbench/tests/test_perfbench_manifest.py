@@ -80,3 +80,11 @@ def test_config_file_states_its_cut(cfg):
     for k in ("source", "deployment", "assumed", "registry_differences"):
         assert spec[k]
     assert any(w["config"] == cfg["name"] for w in MAN["workloads"])
+
+
+@pytest.mark.parametrize("cfg", MAN["configs"], ids=lambda c: c["name"])
+def test_config_names_its_architecture(cfg):
+    spec = json.loads((harness.REPO / cfg["file"]).read_text())
+    arch = harness.architecture(spec["model_type"])
+    for name in ("model_config", "Reference", "step_flops", "NORMS"):
+        assert hasattr(arch, name), (spec["model_type"], name)

@@ -12,21 +12,24 @@ import numpy as np
 import pytest
 
 from perfbench import harness, reference, weights
+from perfbench.arch import llama
 
 SPEC = dict(json.loads((harness.HERE / "configs" / "phi3medium-pp4.json")
                        .read_text()),
             hidden_size=128, intermediate_size=256, num_attention_heads=4,
             num_key_value_heads=2, num_hidden_layers=2, vocab_size=256,
             torch_dtype="float32")
+TARGETS = SPEC["lora"]["targets"]
 
 
 class _Cell:
     config = SPEC
+    arch = llama
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = harness.model_config(SPEC)
+    cfg = llama.model_config(SPEC)
     lcfg = harness.lora_config(_Cell, 3)
     mp, ma = harness.makers(_Cell, cfg, lcfg, 3)
     params = mp(weights.seed_key(2 ** 33 + 1, 0))
@@ -62,13 +65,13 @@ def test_reference_matches_program(model):
         np.int32)
     ids = np.array([0, 1, 2])
     got = program_logits(cfg, params, adapters, tokens, ids)
-    ref = reference.Reference(SPEC)
+    ref = llama.Reference(SPEC)
     want = ref_logits(ref, params, adapters, tokens, ids)
     scale = np.abs(want).max()
     assert np.abs(got - want).max() <= 1e-4 * scale
     wrong = ref_logits(ref, params, adapters, tokens, (ids + 1) % 3)
     assert np.abs(got - wrong).max() > 100 * np.abs(got - want).max()
-    ctl = ref_logits(reference.Reference(SPEC, fp8=True), params, adapters,
+    ctl = ref_logits(llama.Reference(SPEC, fp8=True), params, adapters,
                      tokens, ids)
     assert np.abs(ctl - want).max() > 100 * np.abs(got - want).max()
 
@@ -93,13 +96,13 @@ def test_reference_losses(model):
     got = np.asarray(res.out.ft_loss_sum) / np.asarray(res.out.ft_tok_count)
     one = {k: {"a": v["a"][2][None], "b": v["b"][2][None]}
            for k, v in adapters["blocks"][0].items()
-           if k in reference.LORA_TARGETS}
-    want, g = reference.Reference(SPEC).grads(
+           if k in TARGETS}
+    want, g = llama.Reference(SPEC).grads(
         params, one, [(toks[0], 0.25), (toks[1, :10], 0.25)], 16)
     assert got == pytest.approx(want, rel=1e-5)
     pg = reference.leaves(jax.tree_util.tree_map(lambda x: x[..., 2, :, :],
-                                                 res.grads))
-    rg = reference.leaves(jax.tree_util.tree_map(lambda x: x[0], g))
+                                                 res.grads), TARGETS)
+    rg = reference.leaves(jax.tree_util.tree_map(lambda x: x[0], g), TARGETS)
     assert set(pg) == set(rg) and len(rg) == 14
     for k in rg:
         assert np.abs(pg[k] - rg[k]).max() <= 1e-4 * np.abs(rg[k]).max(), k

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from perfbench import stats
+from perfbench.arch import llama
 from perfbench.stats import Served
 
 
@@ -52,16 +53,16 @@ SPEC = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
 
 
 def test_step_flops():
-    lin = 2 * (stats.layer_params(SPEC) + stats.lora_params(SPEC, 8))
+    lin = 2 * (llama.layer_params(SPEC) + llama.lora_params(SPEC, 8))
     att, head = 4 * 64, 2 * 64 * 100
-    assert stats.layer_params(SPEC) == 64 * 64 * 2 + 64 * 32 * 2 \
+    assert llama.layer_params(SPEC) == 64 * 64 * 2 + 64 * 32 * 2 \
         + 3 * 64 * 128
-    assert stats.step_flops(SPEC, 8, [], [], [10]) == \
+    assert llama.step_flops(SPEC, 8, [], [], [10]) == \
         pytest.approx(3 * (lin + att * 10) + head)
     pf = 3 * (lin * 4 + att * (4 * 6 + 10)) + head
-    assert stats.step_flops(SPEC, 8, [], [(4, 6)], []) == pytest.approx(pf)
+    assert llama.step_flops(SPEC, 8, [], [(4, 6)], []) == pytest.approx(pf)
     ft = 2 * (3 * (lin * 4 + att * 10) + head * 4)
-    assert stats.step_flops(SPEC, 8, [4], [], []) == pytest.approx(ft)
+    assert llama.step_flops(SPEC, 8, [4], [], []) == pytest.approx(ft)
 
 
 def test_unknown_chip_is_an_error():

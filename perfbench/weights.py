@@ -9,14 +9,11 @@ leaf's index), in the dtype the leaf is served in.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-NORMS = ("ln1", "ln2", "final_norm")
-
 
 def seed_key(seed: int, stream: int) -> jax.Array:
     """A raw threefry key from any non-negative whole number (seeds may not
@@ -30,14 +27,16 @@ def _name(path) -> str:
     return str(getattr(last, "key", getattr(last, "idx", last)))
 
 
-def weight_std(path, shape) -> Optional[float]:
-    """Standard deviation of a base-model leaf; None for a norm (ones).
-    The scale the program's own initializer uses: 0.02, or 1/sqrt(fan-in)
-    where that is smaller."""
-    if _name(path) in NORMS:
-        return None
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    return min(0.02, fan_in ** -0.5)
+def weight_std(norms: Sequence[str]) -> Callable:
+    """Standard deviation of a base-model leaf; None for a leaf named in
+    ``norms`` (ones).  The scale the program's own initializer uses: 0.02,
+    or 1/sqrt(fan-in) where that is smaller."""
+    def std(path, shape) -> Optional[float]:
+        if _name(path) in norms:
+            return None
+        fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+        return min(0.02, fan_in ** -0.5)
+    return std
 
 
 def adapter_std(b_scale: float) -> Callable:
